@@ -19,7 +19,7 @@ decision point that consults it:
   :class:`~flox_tpu.profiling.StreamReport` observations
   (:func:`observe_stream` — throughput and overlap fraction per prefetch
   depth and slab band), and seeding from the repo's committed hardware
-  evidence (``BENCH_TPU_LAST.json`` / ``BENCH_HISTORY``, :func:`seed`).
+  evidence (``BENCH_HISTORY``, :func:`seed`).
 * **Decisions** (:func:`decide`): with ``FLOX_TPU_AUTOTUNE=1`` an ``auto``
   policy returns the observed winner for the nearest measured band; without
   a record (or with the tuner off — the default) the existing heuristic
@@ -558,31 +558,21 @@ def _repo_root() -> str:
 
 
 def seed(root: str | None = None) -> int:
-    """Seed the store from the repo's committed measurements: the last
-    on-chip sweep (``BENCH_TPU_LAST.json``) and the newest ``BENCH_HISTORY``
-    round. Records land under the bench workload's bands with
-    ``source="seed"`` so the nearest-band lookup can serve them until real
-    observations replace them. Returns how many records were seeded."""
+    """Seed the store from the repo's committed measurements: the newest
+    ``BENCH_HISTORY`` round. Records land under the bench workload's bands
+    with ``source="seed"`` so the nearest-band lookup can serve them until
+    real observations replace them. Returns how many records were seeded."""
     root = _repo_root() if root is None else root
     seeded = 0
-    for path in (
-        os.path.join(root, "BENCH_TPU_LAST.json"),
-        os.path.join(root, "BENCH_HISTORY", "bench_runs.jsonl"),
-    ):
-        try:
-            with open(path) as f:
-                text = f.read()
-        except OSError:
-            continue
-        lines = text.strip().splitlines()
-        if not lines:
-            continue
-        try:
-            payload = json.loads(lines[-1] if path.endswith(".jsonl") else text)
-        except ValueError:
-            continue
-        if isinstance(payload, dict):
-            seeded += _seed_from_bench_record(payload)
+    path = os.path.join(root, "BENCH_HISTORY", "bench_runs.jsonl")
+    try:
+        with open(path) as f:
+            lines = f.read().strip().splitlines()
+        payload = json.loads(lines[-1]) if lines else None
+    except (OSError, ValueError):
+        payload = None
+    if isinstance(payload, dict):
+        seeded += _seed_from_bench_record(payload)
     logger.debug("autotune: seeded %d record(s) from bench history", seeded)
     return seeded
 

@@ -215,15 +215,10 @@ def clear_all() -> None:
     from .factorize import _FACTORIZE_CACHE, _FACTORIZE_CACHE_BYTES
     from .fusion import _FUSED_PROGRAM_CACHE
     from .kernels import (
-        _PALLAS_COMPILE_PROBE,
-        _PALLAS_MINMAX_COMPILE_PROBE,
         _PALLAS_MINMAX_PROBE_RESULT,
-        _PALLAS_MULTISTAT_COMPILE_PROBE,
         _PALLAS_MULTISTAT_PROBE_RESULT,
         _PALLAS_PROBE_RESULT,
-        _PALLAS_RADIXBIN_COMPILE_PROBE,
         _PALLAS_RADIXBIN_PROBE_RESULT,
-        _PALLAS_SCAN_COMPILE_PROBE,
         _PALLAS_SCAN_PROBE_RESULT,
         _PRESENT_CACHE,
     )
@@ -281,15 +276,10 @@ def clear_all() -> None:
     # after a clear re-validates the backend, which is exactly the fresh
     # state a between-rounds clear promises
     _PALLAS_PROBE_RESULT.clear()
-    _PALLAS_COMPILE_PROBE.clear()
     _PALLAS_MINMAX_PROBE_RESULT.clear()
-    _PALLAS_MINMAX_COMPILE_PROBE.clear()
     _PALLAS_SCAN_PROBE_RESULT.clear()
-    _PALLAS_SCAN_COMPILE_PROBE.clear()
     _PALLAS_MULTISTAT_PROBE_RESULT.clear()
-    _PALLAS_MULTISTAT_COMPILE_PROBE.clear()
     _PALLAS_RADIXBIN_PROBE_RESULT.clear()
-    _PALLAS_RADIXBIN_COMPILE_PROBE.clear()
     # sort-engine present-group tables (content-fingerprint keyed)
     _PRESENT_CACHE.clear()
     # autotune measurement store + its counters/lazy-load flag: clearing

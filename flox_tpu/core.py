@@ -125,7 +125,7 @@ def _jitted_bundle(funcs_key, size: int, engine: str, opts_key: tuple = ()):
 
     specs = funcs_key
 
-    def run(codes, array):
+    def run_rows(codes, array):
         outs = []
         for func, fv, dt, kw in specs:
             outs.append(
@@ -141,6 +141,9 @@ def _jitted_bundle(funcs_key, size: int, engine: str, opts_key: tuple = ()):
                 )
             )
         return tuple(outs)
+
+    def run(codes, array):
+        return utils.map_row_blocks(run_rows, codes, array)
 
     return jax.jit(run)
 

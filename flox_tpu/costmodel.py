@@ -10,8 +10,8 @@ uses. This module reads them:
 
 * **Compiled-program cards** (:func:`ensure_card`): every compile site —
   the eager kernel bundle, the fused multi-statistic program, the mesh
-  shard_map program, the streaming step programs, the Pallas compile
-  probes, and the serve/AOT replays — records one card per (label, input
+  shard_map program, the streaming step programs, and the serve/AOT
+  replays — records one card per (label, input
   signature): analytical flops, bytes accessed, memory footprint, an HLO
   hash, the compile wall, and a roofline ``predicted_ms`` against the
   per-platform peak table. The analysis pass lowers and compiles the SAME
@@ -73,16 +73,20 @@ __all__ = [
     "stamp_capture",
 ]
 
-#: per-platform roofline peaks the predicted-time model divides by:
-#: memory bandwidth (GB/s per chip) and compute (GFLOP/s per chip).
-#: Deliberately conservative round numbers — the model's job is detecting
-#: order-of-magnitude drift and ranking engine families, not citing
-#: datasheets; utilization reads as "fraction of this table's ceiling".
-PEAK_TABLE: dict[str, dict[str, float]] = {
-    "tpu": {"bw_gbps": 819.0, "gflops": 90_000.0},
-    "gpu": {"bw_gbps": 900.0, "gflops": 30_000.0},
-    "cpu": {"bw_gbps": 20.0, "gflops": 100.0},
-    "default": {"bw_gbps": 10.0, "gflops": 50.0},
+#: roofline peaks per chip, keyed by ``device_kind`` as jax reports it:
+#: memory bandwidth (GB/s) and compute (GFLOP/s), each row with its source.
+#: A device missing here is an error, not a default row.
+PEAK_TABLE: dict[str, dict[str, Any]] = {
+    "TPU v5 lite": {
+        "bw_gbps": 819.0,
+        "gflops": 197_000.0,  # bf16
+        "source": "Google Cloud TPU documentation, 'TPU v5e'",
+    },
+    "cpu": {
+        "bw_gbps": 20.0,
+        "gflops": 100.0,
+        "source": "round numbers for the CPU test backend; no datasheet",
+    },
 }
 
 #: digest -> card: the compiled-program card registry. Bounded by program
@@ -120,12 +124,25 @@ def platform_name() -> str:
         return "cpu"
 
 
-def peaks_for(platform: str | None = None) -> dict[str, float]:
-    """The :data:`PEAK_TABLE` row for ``platform`` (default: the active
-    backend), falling back to the ``"default"`` row."""
-    if platform is None:
-        platform = platform_name()
-    return PEAK_TABLE.get(platform, PEAK_TABLE["default"])
+def device_kind() -> str:
+    """``device_kind`` of the first device: the :data:`PEAK_TABLE` key."""
+    import jax
+
+    return str(jax.devices()[0].device_kind)
+
+
+def peaks_for(kind: str | None = None) -> dict[str, Any]:
+    """The :data:`PEAK_TABLE` row for ``kind`` (default: the first
+    device's ``device_kind``); raises for a device the table lacks."""
+    if kind is None:
+        kind = device_kind()
+    try:
+        return PEAK_TABLE[kind]
+    except KeyError:
+        raise ValueError(
+            f"no roofline peaks for device_kind {kind!r}; add its published "
+            f"numbers to costmodel.PEAK_TABLE (known: {sorted(PEAK_TABLE)})"
+        ) from None
 
 
 class serve_alias:
@@ -226,11 +243,11 @@ def _hlo_hash(compiled: Any) -> str | None:
     return hashlib.blake2b(str(text).encode(), digest_size=8).hexdigest()
 
 
-def predicted_ms(card: dict, platform: str | None = None) -> float:
+def predicted_ms(card: dict, kind: str | None = None) -> float:
     """Roofline time for one dispatch of the card's program: the larger of
     the bandwidth leg (bytes accessed / peak GB/s) and the compute leg
     (flops / peak GFLOP/s), in milliseconds."""
-    peaks = peaks_for(platform or card.get("platform"))
+    peaks = peaks_for(kind or card.get("device_kind"))
     bw_s = float(card.get("bytes_accessed", 0.0)) / (peaks["bw_gbps"] * 1e9)
     fl_s = float(card.get("flops", 0.0)) / (peaks["gflops"] * 1e9)
     return max(bw_s, fl_s) * 1e3
@@ -244,8 +261,8 @@ def record_compiled(
     sig: str = "",
     in_shapes: list | None = None,
 ) -> str | None:
-    """Record one card from an already-compiled executable (the Pallas
-    compile probes hold one in hand; :func:`ensure_card` builds one).
+    """Record one card from an already-compiled executable
+    (:func:`ensure_card` builds one).
     Returns the card digest; never raises."""
     try:
         digest = _digest(label, sig)
@@ -254,6 +271,7 @@ def record_compiled(
             "label": label,
             "digest": digest,
             "platform": platform,
+            "device_kind": device_kind(),
             "flops": 0.0,
             "bytes_accessed": 0.0,
             "transcendentals": 0.0,
@@ -316,8 +334,16 @@ def ensure_card(label: str, fn: Any, args: tuple, kwargs: dict | None = None) ->
                 return None
         t0 = time.perf_counter()
         with telemetry.card_compile_accounting():
-            compiled = fn.lower(*args, **(kwargs or {})).compile()
-        compile_ms = (time.perf_counter() - t0) * 1e3
+            lowered = fn.lower(*args, **(kwargs or {}))
+            t1 = time.perf_counter()
+            compiled = lowered.compile()
+        t2 = time.perf_counter()
+        compile_ms = (t2 - t0) * 1e3
+        # counted here, not from jax's backend_compile event: jax reuses
+        # the executable the dispatch just built, so that event may never
+        # fire for an analysis compile
+        telemetry.METRICS.inc("costmodel.card_compiles")
+        telemetry.METRICS.inc("costmodel.card_compile_ms", (t2 - t1) * 1e3)
         # full analysis wall (lowering included), accumulated so wrappers
         # timing a whole dispatch from outside (the serve execute window,
         # AOT warmup) can net it out of their observed device time
@@ -486,6 +512,7 @@ def program_report(top: int | None = None, program: str | None = None) -> dict:
         "programs": rows,
         "peaks": dict(peaks_for()),
         "platform": platform_name(),
+        "device_kind": device_kind(),
         "overhead_ms": overhead,
         "drift_threshold": float(OPTIONS["costmodel_drift_threshold"]),
         # per-dataset attribution: device time billed against resident

@@ -15,6 +15,8 @@ is host-only).
 
 from __future__ import annotations
 
+import os
+from pathlib import Path
 from typing import Any, Sequence
 
 import numpy as np
@@ -22,7 +24,33 @@ import numpy as np
 from . import factorize as fct
 from .aggregations import _initialize_aggregation
 
-__all__ = ["groupby_reduce_device", "codes_device", "memory_stats", "reinitialize"]
+__all__ = [
+    "codes_device",
+    "compile_cache",
+    "groupby_reduce_device",
+    "memory_stats",
+    "reinitialize",
+]
+
+#: the compile cache's home when the environment names none: fixed, since
+#: the path is part of the cache key (a moving directory never hits)
+REPO_COMPILE_CACHE = Path(__file__).resolve().parent.parent / ".jax_cache"
+
+
+def compile_cache(default: Any = None) -> Path:
+    """Turn on JAX's persistent compilation cache and return its directory.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, wins: jax reads it itself and
+    no other directory is set here. Otherwise the cache goes to ``default``
+    (the serve AOT directory) or ``<repo>/.jax_cache`` (gitignored)."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if env:
+        return Path(env)
+    import jax
+
+    path = Path(default) if default is not None else REPO_COMPILE_CACHE
+    jax.config.update("jax_compilation_cache_dir", str(path))
+    return path
 
 
 def reinitialize() -> bool:
@@ -34,25 +62,14 @@ def reinitialize() -> bool:
     compiled-program caches — executables compiled against the dead client
     hold dangling device references and must never be served again. The
     metrics registry, cost ledger, and autotune store are deliberately
-    untouched: recovery is not a stats reset. Returns whether a backend
-    teardown API was found (``False`` degrades to cache-drop-only, which is
+    untouched: recovery is not a stats reset. Returns whether the backend
+    teardown succeeded (``False`` degrades to cache-drop-only, which is
     still the correct half of the story on backends that self-heal).
     Never raises: recovery must be drivable from an error path.
     """
-    import jax
+    import jax.extend.backend
 
-    torn_down = False
-    # the teardown API moved across jax releases; try each spelling
-    holders = (
-        getattr(getattr(jax, "extend", None), "backend", None),
-        getattr(jax, "_src", None) and getattr(jax._src, "api", None),
-        jax,
-    )
-    for holder in holders:
-        fn = getattr(holder, "clear_backends", None) if holder is not None else None
-        if callable(fn) and _teardown_quietly(fn):
-            torn_down = True
-            break
+    torn_down = _teardown_quietly(jax.extend.backend.clear_backends)
     try:
         from .core import _jitted_bundle
         from .fusion import _FUSED_PROGRAM_CACHE
@@ -114,8 +131,8 @@ def memory_stats(devices: Sequence | None = None) -> dict[str, int] | None:
 
 
 def _teardown_quietly(fn: Any) -> bool:
-    """Run one backend-teardown candidate; ``False`` means try the next
-    spelling (recovery proceeds to the cache drop either way)."""
+    """Run the backend teardown; ``False`` if it raised (recovery
+    proceeds to the cache drop either way)."""
     import warnings
 
     try:
@@ -123,7 +140,7 @@ def _teardown_quietly(fn: Any) -> bool:
             warnings.simplefilter("ignore", DeprecationWarning)
             fn()
         return True
-    except Exception:  # noqa: BLE001 — an unavailable spelling, not a fault
+    except Exception:  # noqa: BLE001 — a failed teardown, not a fault
         return False
 
 

@@ -349,11 +349,14 @@ def _aggregate_many_impl(
         telemetry.count("cache.fused_program_misses")
         import jax
 
-        def run(codes_d, array_d):
+        def run_rows(codes_d, array_d):
             inters = fused_chunk_stats(
                 fused, codes_d, array_d, size=size, engine="jax", eager=True
             )
             return fused.finalize_fused(inters)
+
+        def run(codes_d, array_d):
+            return utils.map_row_blocks(run_rows, codes_d, array_d)
 
         program = jax.jit(run)
         _FUSED_PROGRAM_CACHE[key] = program
@@ -543,11 +546,14 @@ def _aggregate_many_prefactorized(
         telemetry.count("cache.fused_program_misses")
         import jax
 
-        def run(codes_d, array_d):
+        def run_rows(codes_d, array_d):
             inters = fused_chunk_stats(
                 fused, codes_d, array_d, size=size, engine="jax", eager=True
             )
             return fused.finalize_fused(inters)
+
+        def run(codes_d, array_d):
+            return utils.map_row_blocks(run_rows, codes_d, array_d)
 
         program = jax.jit(run)
         _FUSED_PROGRAM_CACHE[key] = program

@@ -85,16 +85,11 @@ def _kernel(
             comp_ref[:] = jnp.zeros_like(comp_ref)
 
     codes = codes_ref[0, :]  # (n_tile,)
-    data = data_ref[:]  # (k_tile, n_tile)
+    isnan, ispos, isneg, zeroed = _split_nonfinite(data_ref[:])  # (k_tile, n_tile)
+    nonfinite = isnan | ispos | isneg
     onehot = (
         codes[:, None] == jax.lax.broadcasted_iota(jnp.int32, (n_tile, size_p), 1)
-    ).astype(data.dtype)  # (n_tile, size_p) — lives only in VMEM
-
-    isnan = jnp.isnan(data)
-    ispos = jnp.isposinf(data)
-    isneg = jnp.isneginf(data)
-    nonfinite = isnan | ispos | isneg
-    zeroed = jnp.where(nonfinite, jnp.zeros((), data.dtype), data)
+    ).astype(zeroed.dtype)  # (n_tile, size_p) — lives only in VMEM
 
     def contract(tile, precision):
         # (n_tile, size_p)ᵀ-contract-(k_tile, n_tile) -> (size_p, k_tile).
@@ -121,9 +116,24 @@ def _kernel(
         import jax as _jax
 
         d = _jax.lax.Precision.DEFAULT
-        nan_ref[:] += contract(isnan.astype(data.dtype), d)
-        pos_ref[:] += contract(ispos.astype(data.dtype), d)
-        neg_ref[:] += contract(isneg.astype(data.dtype), d)
+        nan_ref[:] += contract(isnan.astype(zeroed.dtype), d)
+        pos_ref[:] += contract(ispos.astype(zeroed.dtype), d)
+        neg_ref[:] += contract(isneg.astype(zeroed.dtype), d)
+
+
+def _split_nonfinite(data):
+    """NaN / +inf / -inf masks of a data tile and the tile, widened to f32,
+    with them zeroed. bf16 tiles run in f32 inside the kernel (exact):
+    Mosaic on the v5e refused both the packed masks of a bf16 comparison
+    and the bf16 one-hot contraction."""
+    import jax.numpy as jnp
+
+    wide = data.astype(jnp.float32)
+    isnan = jnp.isnan(wide)
+    ispos = jnp.isposinf(wide)
+    isneg = jnp.isneginf(wide)
+    zeroed = jnp.where(isnan | ispos | isneg, jnp.zeros((), wide.dtype), wide)
+    return isnan, ispos, isneg, zeroed
 
 
 def _accum_update(out_ref, comp_ref, onehot, zeroed, contract, accum):
@@ -228,14 +238,22 @@ def _build(
     return jax.jit(fn)
 
 
-def _tiles(n: int, k: int, size: int):
+def _tiles(n: int, k: int, size: int, k_tile_max: int = 512):
     """Shared tiling: lane-axis tiles are multiples of 128 (n for the data
-    blocks, k for the output blocks), sublane rows multiples of 8."""
+    blocks, k for the output blocks), sublane rows multiples of 8.
+
+    Every block must fit v5e's 16 MiB of scoped VMEM, which the compiler
+    enforces and interpret mode does not: past 256 resident group rows the
+    (rows, k_tile) accumulator blocks — up to five, double-buffered — halve
+    k_tile (the dense sum at its 512-group cap needed 18 MiB at 512); the
+    scan passes a lower ``k_tile_max`` for its (k_tile, n_tile) temps."""
     n_tile = 512 if n >= 512 else max(128, -(-n // 128) * 128)
-    k_tile = 512 if k >= 512 else max(128, -(-k // 128) * 128)
+    size_p = max(8, ((size + 7) // 8) * 8)
+    if size_p > 256:
+        k_tile_max = min(k_tile_max, 256)
+    k_tile = min(k_tile_max, max(128, -(-k // 128) * 128))
     n_pad = -(-n // n_tile) * n_tile
     k_pad = -(-k // k_tile) * k_tile
-    size_p = max(8, ((size + 7) // 8) * 8)
     return n_tile, k_tile, n_pad, k_pad, size_p
 
 
@@ -245,11 +263,23 @@ def _minmax_identity(op: str, dtype):
     return minmax_identity(op, dtype)
 
 
-def _minmax_kernel(codes_ref, data_ref, out_ref, *, size, size_p, op):
+def _minmax_kernel(codes_ref, data_ref, out_ref, *, size, op, skip_nan=False):
     """Per-tile grouped min/max on the VPU: one select + lane-reduce per
     group (MXU cannot do the (max, ·) tropical semiring). VPU work scales
     with ``size``, which is why the policy gates on a group-count cap —
-    below it the kernel stays HBM-bound where scatter serializes."""
+    below it the kernel stays HBM-bound where scatter serializes.
+
+    The groups are a ``fori_loop``, not a Python unroll: Mosaic expands
+    each statement over every vreg of the block, so an unrolled loop made
+    compile time grow with ``size × k_tile × n_tile`` (24 s for 12 groups
+    at 512×512 blocks on v5e, and ten times that at the 128-group cap);
+    the loop compiles in under a second at any group count.
+
+    ``skip_nan`` parks NaN lanes at the op's identity — the megakernel's
+    mode, which stages RAW data once for every statistic; the propagating
+    variants re-inject NaN outside from its NaN marker counts. The plain
+    kernel's callers pre-map NaN to absorbing elements themselves."""
+    import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
@@ -261,33 +291,35 @@ def _minmax_kernel(codes_ref, data_ref, out_ref, *, size, size_p, op):
         out_ref[:] = jnp.full_like(out_ref, ident)
 
     codes = codes_ref[0, :]  # (n_tile,)
-    data = data_ref[:]  # (k_tile, n_tile)
+    # widened like the accumulator: bf16 min/max runs in f32 (exact), as
+    # Mosaic cannot store single packed bf16 rows at a dynamic offset
+    data = data_ref[:].astype(out_ref.dtype)  # (k_tile, n_tile)
+    if skip_nan:
+        data = jnp.where(jnp.isnan(data), ident, data)
     combine = jnp.maximum if op == "max" else jnp.minimum
     reduce_ = jnp.max if op == "max" else jnp.min
 
-    rows = []
-    for g in range(size):  # static unroll (size is gated small)
+    def one_group(g, carry):
         # edge-block garbage lanes carry the sentinel code -> identity
         masked = jnp.where((codes == g)[None, :], data, ident)
-        rows.append(reduce_(masked, axis=1))  # (k_tile,)
-    tile_red = jnp.stack(rows)  # (size, k_tile)
-    if size_p > size:
-        tile_red = jnp.concatenate(
-            [tile_red, jnp.full((size_p - size, data.shape[0]), ident, out_ref.dtype)]
-        )
-    out_ref[:] = combine(out_ref[:], tile_red)
+        row = reduce_(masked, axis=1)[None, :]  # (1, k_tile)
+        out_ref[pl.ds(g, 1), :] = combine(out_ref[pl.ds(g, 1), :], row)
+        return carry
+
+    # rows past ``size`` keep the identity from _init (sliced off outside)
+    jax.lax.fori_loop(0, size, one_group, 0)
 
 
 @functools.lru_cache(maxsize=128)
 def _build_minmax(
-    k_pad: int, n_pad: int, size: int, size_p: int, dtype_str: str, n_tile: int,
+    k_pad: int, n_pad: int, size: int, size_p: int, acc_str: str, n_tile: int,
     k_tile: int, interpret: bool, op: str,
 ):
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
-    kern = functools.partial(_minmax_kernel, size=size, size_p=size_p, op=op)
+    kern = functools.partial(_minmax_kernel, size=size, op=op)
     fn = pl.pallas_call(
         kern,
         grid=(k_pad // k_tile, n_pad // n_tile),
@@ -296,7 +328,7 @@ def _build_minmax(
             pl.BlockSpec((k_tile, n_tile), lambda i, j: (i, j)),  # data (K, N)
         ],
         out_specs=pl.BlockSpec((size_p, k_tile), lambda i, j: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((size_p, k_pad), jnp.dtype(dtype_str)),
+        out_shape=jax.ShapeDtypeStruct((size_p, k_pad), jnp.dtype(acc_str)),
         interpret=interpret,
     )
     return jax.jit(fn)
@@ -326,46 +358,15 @@ def segment_minmax_pallas(data, codes, size: int, op: str, *, interpret: bool = 
     codes = jnp.where((codes < 0) | (codes >= size), size_p, codes)
     codes_p = jnp.pad(codes, (0, n_pad - n), constant_values=size_p).reshape(1, n_pad)
 
+    from .kernels import _acc_dtype
+
+    # the accumulator block is the widened dtype (f32 for bf16; exact)
     fn = _build_minmax(
-        k_pad, n_pad, size, size_p, str(flat.dtype), n_tile, k_tile, interpret, op
+        k_pad, n_pad, size, size_p, str(jnp.dtype(_acc_dtype(flat.dtype))),
+        n_tile, k_tile, interpret, op,
     )
     out = fn(codes_p, flat_t)
-    return out[:size, :k].reshape((size,) + orig_shape[1:])
-
-
-def _minmax_accumulate(codes_ref, data_ref, out_ref, *, size, size_p, op):
-    """The min/max accumulation of the multi-statistic megakernel: the
-    ``_minmax_kernel`` select-reduce, but over RAW data (the megakernel
-    stages each tile once for every statistic), so NaN lanes are parked at
-    the op's identity here — the skipna semantics; the propagating
-    variants re-inject NaN outside from the kernel's NaN marker counts."""
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    j = pl.program_id(1)
-    ident = jnp.asarray(_minmax_identity(op, out_ref.dtype), out_ref.dtype)
-
-    @pl.when(j == 0)
-    def _init():
-        out_ref[:] = jnp.full_like(out_ref, ident)
-
-    codes = codes_ref[0, :]  # (n_tile,)
-    data = data_ref[:]  # (k_tile, n_tile)
-    data = jnp.where(jnp.isnan(data), ident, data)
-    combine = jnp.maximum if op == "max" else jnp.minimum
-    reduce_ = jnp.max if op == "max" else jnp.min
-
-    rows = []
-    for g in range(size):  # static unroll (size is gated small)
-        # edge-block garbage lanes carry the sentinel code -> identity
-        masked = jnp.where((codes == g)[None, :], data, ident)
-        rows.append(reduce_(masked, axis=1))  # (k_tile,)
-    tile_red = jnp.stack(rows)  # (size, k_tile)
-    if size_p > size:
-        tile_red = jnp.concatenate(
-            [tile_red, jnp.full((size_p - size, data.shape[0]), ident, out_ref.dtype)]
-        )
-    out_ref[:] = combine(out_ref[:], tile_red)
+    return out[:size, :k].reshape((size,) + orig_shape[1:]).astype(flat.dtype)
 
 
 def _multistat_kernel(
@@ -384,8 +385,8 @@ def _multistat_kernel(
         codes_ref, data_ref, out_ref, nan_ref, pos_ref, neg_ref, comp_ref,
         size_p=size_p, n_tile=n_tile, accum=accum,
     )
-    _minmax_accumulate(codes_ref, data_ref, min_ref, size=size, size_p=size_p, op="min")
-    _minmax_accumulate(codes_ref, data_ref, max_ref, size=size, size_p=size_p, op="max")
+    _minmax_kernel(codes_ref, data_ref, min_ref, size=size, op="min", skip_nan=True)
+    _minmax_kernel(codes_ref, data_ref, max_ref, size=size, op="max", skip_nan=True)
 
 
 @functools.lru_cache(maxsize=128)
@@ -402,14 +403,10 @@ def _build_multistat(
     )
     grid = (k_pad // k_tile, n_pad // n_tile)
     acc = jnp.dtype(acc_str)
-    dt = jnp.dtype(dtype_str)
-    # sums + 3 markers in the accumulator dtype, min/max in the data dtype,
-    # then the optional Kahan/double-double compensation block
-    out_shape = (
-        [jax.ShapeDtypeStruct((size_p, k_pad), acc)] * 4
-        + [jax.ShapeDtypeStruct((size_p, k_pad), dt)] * 2
-        + ([] if accum == "plain" else [jax.ShapeDtypeStruct((size_p, k_pad), acc)])
-    )
+    # sums + 3 markers + min/max, all in the accumulator dtype (min/max of
+    # bf16 data run widened, see _minmax_kernel), then the optional
+    # Kahan/double-double compensation block
+    out_shape = [jax.ShapeDtypeStruct((size_p, k_pad), acc)] * (6 if accum == "plain" else 7)
     fn = pl.pallas_call(
         kern,
         grid=grid,
@@ -472,45 +469,10 @@ def segment_multistat_pallas(
     def crop(x):
         return x[:size, :k].reshape((size,) + orig_shape[1:])
 
-    return crop(sums), crop(nan_c), crop(pos_c), crop(neg_c), crop(mins), crop(maxs)
-
-
-def _probe_card(label: str, compiled, compile_ms: float) -> None:
-    """Record the probe executable's analytical card (costmodel plane):
-    the probe already holds a ``Compiled`` in hand, so the card costs one
-    ``cost_analysis()`` read — no extra compile. No-op when the plane is
-    off; never raises (probe contract)."""
-    try:
-        from . import costmodel
-
-        if costmodel.enabled():
-            costmodel.record_compiled(
-                label, compiled, compile_ms=compile_ms, sig="probe"
-            )
-    except Exception:  # noqa: BLE001 — observability never fails a probe
-        pass
-
-
-def probe_compile_multistat() -> None:
-    """Compile-only probe for the multi-statistic megakernel (see
-    probe_compile)."""
-    import time
-
-    import jax
-    import jax.numpy as jnp
-
-    from .options import OPTIONS
-
-    fn = _build_multistat(
-        128, 128, 2, 8, "float32", "float32", 128, 128, False,
-        str(OPTIONS["pallas_accum"]),
+    return (
+        crop(sums), crop(nan_c), crop(pos_c), crop(neg_c),
+        crop(mins).astype(flat.dtype), crop(maxs).astype(flat.dtype),
     )
-    t0 = time.perf_counter()
-    compiled = fn.lower(
-        jax.ShapeDtypeStruct((1, 128), jnp.int32),
-        jax.ShapeDtypeStruct((128, 128), jnp.float32),
-    ).compile()
-    _probe_card("pallas[multistat]", compiled, (time.perf_counter() - t0) * 1e3)
 
 
 def _scan_kernel(
@@ -801,8 +763,12 @@ def segment_cumsum_pallas(data, codes, size: int, *, skipna: bool, interpret: bo
     k = flat.shape[1]
     flat_t = flat.T  # (K, N) — cancels the caller's moveaxis; no copy
 
-    # one extra carry row for the missing-label group (sentinel == size)
-    n_tile, k_tile, n_pad, _k_pad, size_p = _tiles(n, k, size + 1)
+    # one extra carry row for the missing-label group (sentinel == size);
+    # the scan holds about a dozen (k_tile, n_tile) temporaries in VMEM,
+    # which at k_tile=512 overflowed the 16 MiB limit (19 MiB), and its
+    # five branches compile in time proportional to the block (k_tile=256:
+    # ~10 s at the group cap; 128: ~3 s)
+    n_tile, k_tile, n_pad, _k_pad, size_p = _tiles(n, k, size + 1, k_tile_max=128)
 
     codes = jnp.asarray(codes).astype(jnp.int32).reshape(-1)
     codes = jnp.where((codes < 0) | (codes >= size), size, codes)
@@ -816,61 +782,6 @@ def segment_cumsum_pallas(data, codes, size: int, *, skipna: bool, interpret: bo
     )
     out, *_carries = fn(codes_p, flat_t)
     return out.T.reshape(orig_shape)
-
-
-def probe_compile_scan() -> None:
-    """Compile-only probe for the scan kernel (see probe_compile)."""
-    import time
-
-    import jax
-    import jax.numpy as jnp
-
-    fn = _build_scan(128, 128, 128, 8, "float32", "float32", 128, 128, False, False)
-    t0 = time.perf_counter()
-    compiled = fn.lower(
-        jax.ShapeDtypeStruct((1, 128), jnp.int32),
-        jax.ShapeDtypeStruct((128, 128), jnp.float32),
-    ).compile()
-    _probe_card("pallas[scan]", compiled, (time.perf_counter() - t0) * 1e3)
-
-
-def probe_compile_minmax() -> None:
-    """Compile-only probe for the min/max kernel (see probe_compile)."""
-    import time
-
-    import jax
-    import jax.numpy as jnp
-
-    fn = _build_minmax(128, 128, 2, 8, "float32", 128, 128, False, "max")
-    t0 = time.perf_counter()
-    compiled = fn.lower(
-        jax.ShapeDtypeStruct((1, 128), jnp.int32),
-        jax.ShapeDtypeStruct((128, 128), jnp.float32),
-    ).compile()
-    _probe_card("pallas[minmax]", compiled, (time.perf_counter() - t0) * 1e3)
-
-
-def probe_compile() -> None:
-    """Lower + compile a tiny instance of the kernel on the real backend
-    WITHOUT executing it — safe to call while an outer jit is tracing
-    (no concrete arrays are created, so nothing can leak a tracer)."""
-    import time
-
-    import jax
-    import jax.numpy as jnp
-
-    from .options import OPTIONS
-
-    fn = _build(
-        128, 128, 8, "float32", "float32", 128, 128, False,
-        str(OPTIONS["pallas_accum"]),
-    )
-    t0 = time.perf_counter()
-    compiled = fn.lower(
-        jax.ShapeDtypeStruct((1, 128), jnp.int32),
-        jax.ShapeDtypeStruct((128, 128), jnp.float32),
-    ).compile()
-    _probe_card("pallas[segment_sum]", compiled, (time.perf_counter() - t0) * 1e3)
 
 
 def segment_sum_pallas(
@@ -999,16 +910,12 @@ def _radixbin_kernel(
         # sentinel g_tile matches no one-hot column: out-of-block rows (and
         # the caller's missing/pad sentinel) contract to exactly 0.0
         codes = jnp.where(inblock, local, g_tile)
+        isnan, ispos, isneg, zeroed = _split_nonfinite(data)
+        nonfinite = isnan | ispos | isneg
         onehot = (
             codes[:, None]
             == jax.lax.broadcasted_iota(jnp.int32, (n_tile, g_tile), 1)
-        ).astype(data.dtype)  # (n_tile, g_tile) — lives only in VMEM
-
-        isnan = jnp.isnan(data)
-        ispos = jnp.isposinf(data)
-        isneg = jnp.isneginf(data)
-        nonfinite = isnan | ispos | isneg
-        zeroed = jnp.where(nonfinite, jnp.zeros((), data.dtype), data)
+        ).astype(zeroed.dtype)  # (n_tile, g_tile) — lives only in VMEM
 
         def contract(tile, precision):
             return jax.lax.dot_general(
@@ -1026,9 +933,9 @@ def _radixbin_kernel(
         @pl.when(jnp.any(nonfinite & inblock[None, :]))
         def _markers():
             d = jax.lax.Precision.DEFAULT
-            nan_ref[:] += contract(isnan.astype(data.dtype), d)
-            pos_ref[:] += contract(ispos.astype(data.dtype), d)
-            neg_ref[:] += contract(isneg.astype(data.dtype), d)
+            nan_ref[:] += contract(isnan.astype(zeroed.dtype), d)
+            pos_ref[:] += contract(ispos.astype(zeroed.dtype), d)
+            neg_ref[:] += contract(isneg.astype(zeroed.dtype), d)
 
 
 @functools.lru_cache(maxsize=128)
@@ -1119,24 +1026,3 @@ def segment_sum_radixbin_pallas(
     return reapply_nonfinite(
         crop(sums), crop(nan_c), crop(pos_c), crop(neg_c), skipna=skipna
     )
-
-
-def probe_compile_radixbin() -> None:
-    """Compile-only probe for the radix-binning kernel (see probe_compile)."""
-    import time
-
-    import jax
-    import jax.numpy as jnp
-
-    from .options import OPTIONS
-
-    fn = _build_radixbin(
-        128, 128, 16, 8, "float32", "float32", 128, 128, False,
-        str(OPTIONS["pallas_accum"]),
-    )
-    t0 = time.perf_counter()
-    compiled = fn.lower(
-        jax.ShapeDtypeStruct((1, 128), jnp.int32),
-        jax.ShapeDtypeStruct((128, 128), jnp.float32),
-    ).compile()
-    _probe_card("radixbin[segment_sum]", compiled, (time.perf_counter() - t0) * 1e3)

@@ -696,6 +696,18 @@ def _build_program(
 
         return generic_kernel(f, codes_sh, arr_sh, size=size, fill_value=None, **kw)
 
+    def local_stage(codes_sh, arr_sh):
+        """This shard's counts and chunk intermediates, over blocks of its
+        kept rows when the shard is large (``utils.map_row_blocks``), so
+        their device temps stay bounded."""
+        return utils.map_row_blocks(
+            lambda c, a: (
+                _local_counts(c, a, size, count_skipna, nat),
+                _local_chunk(agg, c, a, size, nat),
+            ),
+            codes_sh, arr_sh,
+        )
+
     def orderstat_program(arr_sh, codes_sh):
         """Distributed quantile/median: ONE kernel call whose radix-select
         counting passes psum across shards (kernels._quantile_impl
@@ -710,10 +722,8 @@ def _build_program(
         return _apply_final_fill(result, counts, agg)
 
     def mapreduce_program(arr_sh, codes_sh):
-        counts_local = _local_counts(codes_sh, arr_sh, size, count_skipna, nat)
-        counts = jax.lax.psum(counts_local, axis_name)
-
         if agg.reduction_type == "argreduce":
+            counts_local = _local_counts(codes_sh, arr_sh, size, count_skipna, nat)
             val_f, arg_f = agg.chunk  # e.g. ("max", "argmax")
             from ..kernels import generic_kernel
 
@@ -727,6 +737,7 @@ def _build_program(
             gidx = jnp.where(local_arg >= 0, local_arg + offset, -1)
             inters = [val, gidx]
         elif agg.combine in (("first",), ("last",)):
+            counts_local = _local_counts(codes_sh, arr_sh, size, count_skipna, nat)
             offset = _flat_axis_index(axis_name).astype(jnp.int32) * shard_len
             val, pos = _local_firstlast(
                 codes_sh, arr_sh, size, skipna=skipna,
@@ -734,7 +745,8 @@ def _build_program(
             )
             inters = [val, pos]
         else:
-            inters = _local_chunk(agg, codes_sh, arr_sh, size, nat)
+            counts_local, inters = local_stage(codes_sh, arr_sh)
+        counts = jax.lax.psum(counts_local, axis_name)
         combined = _combine_intermediates(agg, inters, axis_name, nat)
         return finalize(combined, counts)
 
@@ -812,13 +824,13 @@ def _build_program(
                 return jnp.take(full, inv_c, axis=-1)
             return _crop(full, size)
 
-        counts_local = to_slots(_local_counts(codes_sh, arr_sh, size, count_skipna, nat))
+        counts_raw, inters = local_stage(codes_sh, arr_sh)
+        counts_local = to_slots(counts_raw)
         counts_own = jax.lax.psum_scatter(
             jnp.moveaxis(counts_local, -1, 0), axis_name, scatter_dimension=0, tiled=True
         )
         counts_own = jnp.moveaxis(counts_own, 0, -1)
 
-        inters = _local_chunk(agg, codes_sh, arr_sh, size, nat)
         owned = []
         for inter, op in zip(inters, agg.combine):
             if op == "var":

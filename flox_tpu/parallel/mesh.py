@@ -17,52 +17,21 @@ import numpy as np
 def shard_map(
     f: Callable, *, mesh: Any, in_specs: Any, out_specs: Any, check_vma: bool = True
 ) -> Callable:
-    """Version-compat wrapper over ``jax.shard_map``.
-
-    ``jax.shard_map`` only exists as a top-level API in newer jax; older
-    releases ship it as ``jax.experimental.shard_map.shard_map`` with the
-    replication-check keyword spelled ``check_rep`` instead of ``check_vma``.
-    Every shard_map construction in the package goes through here (floxlint
-    FLX004 flags bare ``jax.shard_map`` attribute access) so the fallback and
-    the keyword translation live in exactly one place.
-    """
-    import inspect
-
+    """``jax.shard_map``. Every shard_map construction in the package goes
+    through here (floxlint FLX004 flags bare ``jax.shard_map`` attribute
+    access), so the one spelling lives in one place."""
     import jax
 
-    native = getattr(jax, "shard_map", None)  # floxlint: disable=FLX004
-    if native is not None:
-        # transitional releases expose jax.shard_map but still spell the
-        # replication-check kwarg check_rep; probe the signature rather than
-        # retrying on TypeError (which would mask real construction errors)
-        try:
-            params = inspect.signature(native).parameters
-        except (TypeError, ValueError):
-            params = {}
-        kwarg = "check_vma" if "check_vma" in params or not params else "check_rep"
-        return native(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **{kwarg: check_vma}
-        )
-    from jax.experimental.shard_map import (  # floxlint: disable=FLX004
-        shard_map as experimental_shard_map,
-    )
-
-    return experimental_shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=check_vma
+    return jax.shard_map(  # floxlint: disable=FLX004
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=check_vma
     )
 
 
 def axis_size(axis_name: str) -> int:
-    """Version-compat ``jax.lax.axis_size``: newer jax has it as an API;
-    older releases get it via the constant-folding idiom ``psum(1, axis)``,
-    which resolves to a static int at trace time. FLX004 flags bare
-    ``jax.lax.axis_size`` access so the fallback lives here only."""
+    """``jax.lax.axis_size`` (FLX004 flags bare access, as for shard_map)."""
     import jax
 
-    fn = getattr(jax.lax, "axis_size", None)  # floxlint: disable=FLX004
-    if fn is not None:
-        return fn(axis_name)
-    return jax.lax.psum(1, axis_name)
+    return jax.lax.axis_size(axis_name)  # floxlint: disable=FLX004
 
 
 def make_mesh(

@@ -249,6 +249,10 @@ async def _amain(args: argparse.Namespace) -> int:
 
     if args.aot_dir:
         set_options(serve_aot_dir=args.aot_dir)
+    if OPTIONS["serve_aot_dir"] is None:
+        from ..device import compile_cache
+
+        compile_cache()  # with an AOT dir, aot.configure places the cache
     if args.replica_id:
         # validated like any set_options value (label-safe, bounded): a
         # bad --replica-id dies at startup, not at first scrape

@@ -77,11 +77,11 @@ def configure(path: Any = None) -> Path | None:
     """Point JAX's persistent compilation cache at the AOT directory.
 
     ``path`` defaults to ``OPTIONS["serve_aot_dir"]``; ``None`` there means
-    persistence is off and this is a no-op returning ``None``. Idempotent
-    per directory; safe to call before every dispatch (the dispatcher
-    does). Never raises: a jax too old for the cache config knobs degrades
-    to in-process caching with a warning — serving still works, restarts
-    just pay the compile.
+    persistence is off and this is a no-op returning ``None``. A
+    ``JAX_COMPILATION_CACHE_DIR`` in the environment keeps the executables
+    (``device.compile_cache``); the manifest stays in the AOT directory.
+    Idempotent per directory; safe to call before every dispatch (the
+    dispatcher does).
     """
     root = _aot_dir(path)
     if root is None:
@@ -90,23 +90,17 @@ def configure(path: Any = None) -> Path | None:
         if _STATE["configured"] == str(root):
             return root
         root.mkdir(parents=True, exist_ok=True)
-        try:
-            import jax
+        import jax
 
-            jax.config.update("jax_compilation_cache_dir", str(root))
-            # the default floors skip programs that compile fast or lower
-            # small — on a CPU test rig that is every program, and on TPU a
-            # skipped "fast" compile is still a first-request stall. Persist
-            # everything; the dir is bounded by what the replica serves.
-            jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        except Exception as exc:  # noqa: BLE001 — version drift must not break serving
-            telemetry.record_serve_error(exc, what="aot.configure")
-            logger.warning(
-                "persistent compilation cache unavailable (jax too old?); "
-                "AOT warmup will re-trace but restarts pay full compiles"
-            )
-            return None
+        from ..device import compile_cache
+
+        compile_cache(root)
+        # the default floors skip programs that compile fast or lower
+        # small — on a CPU test rig that is every program, and on TPU a
+        # skipped "fast" compile is still a first-request stall. Persist
+        # everything; the dir is bounded by what the replica serves.
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         if _STATE["configured"] is not None:
             logger.info("AOT cache dir moved %s -> %s", _STATE["configured"], root)
         _STATE["configured"] = str(root)
@@ -121,12 +115,10 @@ def deconfigure() -> None:
     with _LOCK:
         if _STATE["configured"] is None:
             return
-        try:
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
             import jax
 
             jax.config.update("jax_compilation_cache_dir", None)
-        except Exception as exc:  # noqa: BLE001
-            telemetry.record_serve_error(exc, what="aot.deconfigure")
         _STATE["configured"] = None
 
 

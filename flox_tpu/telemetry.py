@@ -1524,23 +1524,17 @@ def _install_jax_listener() -> None:
 
     The listener registers once and gates on :func:`enabled` per event, so a
     later ``set_options(telemetry=False)`` stops the counting without
-    needing (unsupported) listener removal. A jax without ``monitoring``
-    degrades to the cache-layer counters only."""
-    try:
-        from jax import monitoring
-    except Exception:  # noqa: BLE001 — version drift must not break import
-        return
+    needing (unsupported) listener removal."""
+    from jax import monitoring
 
     def _on_duration(name: str, duration_s: float, **kw: Any) -> None:
         if not enabled():
             return
         if getattr(_COMPILE_ROUTE, "route", None) == "costmodel":
-            # card-analysis compiles: real wall, but not served programs —
-            # routed so `jax.compiles` keeps meaning NEW backend work
-            if name.endswith("backend_compile_duration"):
-                METRICS.inc("costmodel.card_compiles")
-                METRICS.inc("costmodel.card_compile_ms", duration_s * 1e3)
-            elif name.endswith("jaxpr_trace_duration"):
+            # card-analysis compiles are not served programs: kept off
+            # `jax.compiles` so it keeps meaning NEW backend work
+            # (costmodel.ensure_card counts card_compiles itself)
+            if name.endswith("jaxpr_trace_duration"):
                 METRICS.inc("costmodel.card_traces")
             return
         if name.endswith("backend_compile_duration"):
@@ -1574,14 +1568,8 @@ def _install_jax_listener() -> None:
         elif name.endswith("compilation_cache/cache_misses"):
             METRICS.inc("jax.persistent_cache_misses")
 
-    try:
-        monitoring.register_event_duration_secs_listener(_on_duration)
-    except Exception:  # noqa: BLE001
-        return
-    try:
-        monitoring.register_event_listener(_on_event)
-    except Exception:  # noqa: BLE001 — older jax without plain-event
-        pass  # listeners keeps the duration counters; hits go uncounted
+    monitoring.register_event_duration_secs_listener(_on_duration)
+    monitoring.register_event_listener(_on_event)
 
 
 # ---------------------------------------------------------------------------

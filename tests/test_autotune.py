@@ -560,11 +560,10 @@ class TestSeedAndSentinel:
         }
 
     def test_seed_from_bench_files(self, tmp_path):
-        with open(tmp_path / "BENCH_TPU_LAST.json", "w") as f:
-            json.dump(self._bench_record(), f)
         os.makedirs(tmp_path / "BENCH_HISTORY")
         with open(tmp_path / "BENCH_HISTORY" / "bench_runs.jsonl", "w") as f:
             f.write(json.dumps(self._bench_record("cpu")) + "\n")
+            f.write(json.dumps(self._bench_record()) + "\n")
         assert autotune.seed(str(tmp_path)) > 0
         # the seeded on-chip numbers resolve the open quantile decision for
         # the tpu platform key (this CPU process keys decide() by its own

@@ -692,3 +692,50 @@ class TestPinnedEdgeSemantics:
         codes = np.zeros(5, dtype=np.int64)
         got, _ = groupby_reduce(vals, codes, func="mode", engine=engine)
         assert float(np.asarray(got)[0]) == 3.0
+
+
+@pytest.mark.parametrize(
+    "func, kw",
+    [("nanvar", {}), ("nanmax", {}), ("nanquantile", {"finalize_kwargs": {"q": [0.25, 0.75]}}),
+     ("nanargmin", {})],
+)
+def test_row_blocks_match_unblocked(monkeypatch, func, kw):
+    # past utils.ROW_BLOCK_BYTES the eager and fused programs run the kept
+    # rows in sequential blocks (bounded device temps); rows reduce on their
+    # own, so the answer must not move — tail block and nD kept dims included
+    import flox_tpu
+    from flox_tpu import cache, utils
+    from flox_tpu.fusion import groupby_aggregate_many
+
+    vals = RNG.normal(size=(5, 7, 96))
+    vals[0, 1, ::7] = np.nan
+    labels = np.arange(96) % 5
+    cache.clear_all()
+    want, _ = groupby_reduce(vals, labels, func=func, engine="jax", **kw)
+    want_many, _ = groupby_aggregate_many(vals, labels, funcs=("mean", "var", "max"), engine="jax")
+    monkeypatch.setattr(utils, "ROW_BLOCK_BYTES", 8 * 96 * 8)  # 8-row blocks of 35 rows
+    cache.clear_all()
+    got, _ = groupby_reduce(vals, labels, func=func, engine="jax", **kw)
+    got_many, _ = groupby_aggregate_many(vals, labels, funcs=("mean", "var", "max"), engine="jax")
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    for name in want_many:
+        np.testing.assert_array_equal(np.asarray(got_many[name]), np.asarray(want_many[name]))
+    with flox_tpu.set_options(telemetry=False):
+        cache.clear_all()
+
+
+@pytest.mark.parametrize("method", ["map-reduce", "cohorts"])
+def test_row_blocks_match_unblocked_on_the_mesh(monkeypatch, method):
+    # the mesh chunk stage row-blocks each shard the same way
+    from flox_tpu import cache, utils
+
+    vals = RNG.normal(size=(40, 64))
+    vals[3, ::5] = np.nan
+    labels = np.arange(64) % 6
+    cache.clear_all()
+    want, _ = groupby_reduce(vals, labels, func="nanvar", method=method)
+    monkeypatch.setattr(utils, "ROW_BLOCK_BYTES", 8 * 8 * 8)  # shards of 8 steps
+    cache.clear_all()
+    got, _ = groupby_reduce(vals, labels, func="nanvar", method=method)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    cache.clear_all()

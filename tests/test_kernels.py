@@ -815,9 +815,10 @@ def test_complex_nan_fill_keeps_imaginary():
     assert b.dtype.kind == "c" and b[0] == 6 + 3j and np.isnan(b[1].real)
 
 
-def test_pallas_probe_failure_falls_back(monkeypatch):
-    # if the pallas kernel cannot lower on the real backend, auto/pallas
-    # policies must degrade to the XLA paths instead of failing the reduction
+@pytest.mark.parametrize("policy", ["pallas", "auto"])
+def test_pallas_probe_failure_raises_on_tpu(monkeypatch, policy):
+    # a kernel the chip's compiler refuses must fail the reduction, never
+    # hand it quietly to the XLA paths (a broken kernel would read as slow)
     import flox_tpu
     from flox_tpu import kernels as K
     from flox_tpu import pallas_kernels
@@ -829,12 +830,27 @@ def test_pallas_probe_failure_falls_back(monkeypatch):
     monkeypatch.setattr("jax.default_backend", lambda: "tpu")
     import jax.numpy as jnp
 
-    with flox_tpu.set_options(segment_sum_impl="pallas"):
-        assert K._segment_sum_impl(jnp.zeros((64, 4), jnp.float32), 12) == "scatter"
-    monkeypatch.setattr(K, "_PALLAS_PROBE_RESULT", [])
-    with flox_tpu.set_options(segment_sum_impl="auto"):
-        # auto degrades pallas -> matmul (guards pass) on a TPU backend
-        assert K._segment_sum_impl(jnp.zeros((64, 4), jnp.float32), 12) == "matmul"
+    with flox_tpu.set_options(segment_sum_impl=policy):
+        with pytest.raises(RuntimeError, match="lowering failed"):
+            K._segment_sum_impl(jnp.zeros((64, 4), jnp.float32), 12)
+    assert K._PALLAS_PROBE_RESULT == []
+
+
+def test_pallas_probe_wrong_result_raises_on_tpu(monkeypatch):
+    import flox_tpu
+    from flox_tpu import kernels as K
+    from flox_tpu import pallas_kernels
+
+    monkeypatch.setattr(K, "_PALLAS_SCAN_PROBE_RESULT", [])
+    monkeypatch.setattr(
+        pallas_kernels, "segment_cumsum_pallas", lambda d, *a, **k: d * 0
+    )
+    monkeypatch.setattr("jax.default_backend", lambda: "tpu")
+    import jax.numpy as jnp
+
+    with flox_tpu.set_options(scan_impl="auto"):
+        with pytest.raises(RuntimeError, match="wrong result"):
+            K._scan_impl_choice(jnp.zeros((64, 4), jnp.float32), 12)
 
 
 def test_quantile_bf16_large_group():
@@ -1155,3 +1171,22 @@ class TestRadixSelectQuantile:
                 "quantile", codes, data, 4, q=0.4, method=method, dtype=dt
             )
             np.testing.assert_array_equal(a, b)
+
+
+def test_pallas_probe_runs_outside_the_callers_trace(monkeypatch):
+    # the policy is consulted while jit (or shard_map) traces; the probe
+    # must still execute on concrete arrays, not trace into the caller
+    import jax
+    import jax.numpy as jnp
+
+    from flox_tpu import kernels as K
+    from flox_tpu import pallas_kernels
+
+    monkeypatch.setattr(K, "_PALLAS_SCAN_PROBE_RESULT", [])
+    monkeypatch.setattr(
+        pallas_kernels, "segment_cumsum_pallas", lambda d, *a, **k: jnp.cumsum(d, axis=0)
+    )
+    monkeypatch.setattr("jax.default_backend", lambda: "tpu")
+    picked = []
+    jax.jit(lambda x: picked.append(K._scan_impl_choice(x, 12)) or x)(jnp.zeros((64, 4), jnp.float32))
+    assert picked == ["pallas"] and K._PALLAS_SCAN_PROBE_RESULT == [True]

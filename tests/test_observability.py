@@ -1514,3 +1514,15 @@ class TestAutotunePrior:
             )
             assert choice == "fused"
             assert telemetry.METRICS.get("costmodel.prior_consults") == 0
+
+
+@pytest.mark.parametrize(
+    "kind, bw", [("TPU v5 lite", 819.0), ("cpu", 20.0), ("TPU v9 imaginary", None)]
+)
+def test_peak_table_is_keyed_by_device_kind(kind, bw):
+    if bw is None:
+        with pytest.raises(ValueError, match="no roofline peaks"):
+            costmodel.peaks_for(kind)
+    else:
+        row = costmodel.peaks_for(kind)
+        assert row["bw_gbps"] == bw and row["source"]

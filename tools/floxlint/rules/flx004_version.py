@@ -20,9 +20,9 @@ from .common import ImportMap, dotted_name
 #: canonical attribute paths that MUST be reached through a compat shim,
 #: mapped to the remediation message
 _GATED_APIS = {
-    "jax.shard_map": "use flox_tpu.parallel.mesh.shard_map (falls back to jax.experimental.shard_map and maps check_vma->check_rep)",
+    "jax.shard_map": "use flox_tpu.parallel.mesh.shard_map",
     "jax.experimental.shard_map": "import it only inside the flox_tpu.parallel.mesh.shard_map shim",
-    "jax.lax.axis_size": "use flox_tpu.parallel.mesh.axis_size (falls back to the static psum(1, axis) idiom)",
+    "jax.lax.axis_size": "use flox_tpu.parallel.mesh.axis_size",
     "jax.tree_map": "removed in newer jax; use jax.tree.map",
     "jax.tree_multimap": "removed in newer jax; use jax.tree.map",
     "jax.tree_util.tree_multimap": "removed in newer jax; use jax.tree.map",
