@@ -142,10 +142,11 @@ def _jitted_bundle(funcs_key, size: int, engine: str, opts_key: tuple = ()):
             )
         return tuple(outs)
 
-    def run(codes, array):
+    def flox_bundle(codes, array):
         return utils.map_row_blocks(run_rows, codes, array)
 
-    return jax.jit(run)
+    # the name is the program's in the profiler's trace
+    return jax.jit(flox_bundle)
 
 
 def chunk_reduce(

@@ -353,12 +353,13 @@ def _aggregate_many_impl(
             inters = fused_chunk_stats(
                 fused, codes_d, array_d, size=size, engine="jax", eager=True
             )
-            return fused.finalize_fused(inters)
+            with jax.named_scope("flox.finalize"):
+                return fused.finalize_fused(inters)
 
-        def run(codes_d, array_d):
+        def flox_fused(codes_d, array_d):
             return utils.map_row_blocks(run_rows, codes_d, array_d)
 
-        program = jax.jit(run)
+        program = jax.jit(flox_fused)
         _FUSED_PROGRAM_CACHE[key] = program
     else:
         telemetry.count("cache.fused_program_hits")
@@ -550,12 +551,13 @@ def _aggregate_many_prefactorized(
             inters = fused_chunk_stats(
                 fused, codes_d, array_d, size=size, engine="jax", eager=True
             )
-            return fused.finalize_fused(inters)
+            with jax.named_scope("flox.finalize"):
+                return fused.finalize_fused(inters)
 
-        def run(codes_d, array_d):
+        def flox_fused(codes_d, array_d):
             return utils.map_row_blocks(run_rows, codes_d, array_d)
 
-        program = jax.jit(run)
+        program = jax.jit(flox_fused)
         _FUSED_PROGRAM_CACHE[key] = program
     else:
         telemetry.count("cache.fused_program_hits")

@@ -98,6 +98,11 @@ def _acc_dtype(dt):
     return dt
 
 
+#: the name scope of every segment-reduction lowering (Pallas or XLA), as the
+#: profiler's trace names the ops under it
+KERNEL_SCOPE = "flox.kernel"
+
+
 def _on_tpu() -> bool:
     """One definition of "on the TPU": the pallas kernels run natively
     there and in interpret mode (the CPU tests) anywhere else, and the auto
@@ -812,12 +817,13 @@ def fused_segment_stats(group_idx, array, *, size: int, want: tuple):
     """Plugin-layout entry to :func:`_fused_stats_leading`: ``array``
     (..., N) in, ``{name: (..., size)}`` out (or None) — what the fusion
     planner's chunk executor calls."""
-    codes = _safe_codes(group_idx, size)
-    data = _to_leading(array)
-    raw = _fused_stats_leading(data, codes, size, tuple(want))
-    if raw is None:
-        return None
-    return {k: _from_leading(v) for k, v in raw.items()}
+    with jax.named_scope(KERNEL_SCOPE):
+        codes = _safe_codes(group_idx, size)
+        data = _to_leading(array)
+        raw = _fused_stats_leading(data, codes, size, tuple(want))
+        if raw is None:
+            return None
+        return {k: _from_leading(v) for k, v in raw.items()}
 
 
 def _fused_sum_counts(cast, codes, size: int):
@@ -1700,7 +1706,8 @@ def generic_kernel(func: str, group_idx, array, **kwargs):
         # retrace signal (executions are fused into compiled programs);
         # eager (jit=False) calls count once per execution
         telemetry.METRICS.inc(f"kernel.trace.{func}")
-    return fn(group_idx, array, **kwargs)
+    with jax.named_scope(KERNEL_SCOPE):
+        return fn(group_idx, array, **kwargs)
 
 
 # ---------------------------------------------------------------------------

@@ -234,6 +234,7 @@ def _build(
         out_specs=[pl.BlockSpec((size_p, k_tile), lambda i, j: (0, i))] * n_out,
         out_shape=out_shape,
         interpret=interpret,
+        name="flox_segment_sum",
     )
     return jax.jit(fn)
 
@@ -330,6 +331,7 @@ def _build_minmax(
         out_specs=pl.BlockSpec((size_p, k_tile), lambda i, j: (0, i)),
         out_shape=jax.ShapeDtypeStruct((size_p, k_pad), jnp.dtype(acc_str)),
         interpret=interpret,
+        name="flox_minmax",
     )
     return jax.jit(fn)
 
@@ -417,6 +419,7 @@ def _build_multistat(
         out_specs=[pl.BlockSpec((size_p, k_tile), lambda i, j: (0, i))] * len(out_shape),
         out_shape=out_shape,
         interpret=interpret,
+        name="flox_multistat",
     )
     return jax.jit(fn)
 
@@ -745,6 +748,7 @@ def _build_scan(
         out_shape=[jax.ShapeDtypeStruct((k, n), jnp.dtype(dtype_str))]
         + [jax.ShapeDtypeStruct((size_p, k_tiles * k_tile), acc)] * (3 if skipna else 4),
         interpret=interpret,
+        name="flox_segment_cumsum",
     )
     return jax.jit(fn)
 
@@ -965,6 +969,7 @@ def _build_radixbin(
         out_specs=[pl.BlockSpec((g_tile, k_tile), lambda i, g, j: (g, i))] * n_out,
         out_shape=out_shape,
         interpret=interpret,
+        name="flox_radixbin",
     )
     return jax.jit(fn)
 

@@ -482,86 +482,88 @@ def sharded_groupby_reduce(
         )
         cohort_perm = ownership_permutation(mapping, size, ndev)
 
-    arr = utils.asarray_device(array)
-    if utils.is_jax_array(codes):
-        # pre-staged device codes (a registry put / factorize.Prefactorized
-        # feeds its per-shard codes straight in): skip the host round-trip —
-        # the put already paid the one H2D
-        codes_dev = codes if codes.dtype == jnp.int32 else codes.astype(jnp.int32)
-    else:
-        codes_dev = jnp.asarray(np.asarray(codes), dtype=jnp.int32)
-    n = codes_dev.shape[0]
-    pad = _pad_to(n, ndev)
-    if pad:
-        codes_dev = jnp.concatenate([codes_dev, jnp.full((pad,), -1, dtype=jnp.int32)])
-        widths = [(0, 0)] * (arr.ndim - 1) + [(0, pad)]
-        arr = jnp.pad(arr, widths)
-    shard_len = codes_dev.shape[0] // ndev
-
-    # pad the group axis for psum_scatter ownership slicing
-    size_pad = size + _pad_to(n=size, multiple=ndev) if method == "cohorts" else size
-
-    spec_entry = axes if len(axes) > 1 else axes[0]
-    in_specs = (
-        P(*([None] * (arr.ndim - 1) + [spec_entry])),
-        P(spec_entry),
-    )
-    out_specs = P()  # replicated
-
+    from .. import telemetry
     from ..options import trace_fingerprint
 
-    cache_key = (
-        _agg_cache_key(agg), size, size_pad, method, axes, shard_len, nat,
-        mesh, arr.ndim, blocked, trace_fingerprint(),
-        None if cohort_perm is None else cohort_perm.tobytes(),
-    )
-    from .. import telemetry
+    with telemetry.span("dispatch", method=method, ndev=ndev, size=size):
+        arr = utils.asarray_device(array)
+        n = int(np.shape(codes)[0])
+        pad = _pad_to(n, ndev)
 
-    tm_on = telemetry.enabled()
-    if tm_on:
-        # cost-ledger baseline: dispatch wall + the jax-compile delta this
-        # mesh dispatch provokes (the build path's first run pays the
-        # trace+compile; the hit path should read ~0 compiles)
-        compiles0 = telemetry.METRICS.get("jax.compiles")
-        compile_ms0 = telemetry.METRICS.get("jax.compile_ms")
-        t_dispatch0 = perf_counter()
+        def padded(c):
+            return jnp.concatenate([c, jnp.full((pad,), -1, dtype=jnp.int32)]) if pad else c
 
-    fn = _PROGRAM_CACHE.get(cache_key)
-    if fn is None:
-        telemetry.count("cache.program_misses")
-        program = _build_program(
-            agg, size=size, size_pad=size_pad, method=method, axis_name=axes,
-            shard_len=shard_len, nat=nat, cohort_perm=cohort_perm,
-            blocked=blocked, ndev=ndev,
+        if utils.is_jax_array(codes):
+            # pre-staged device codes (a registry put / factorize.Prefactorized
+            # feeds its per-shard codes straight in): skip the host round-trip —
+            # the put already paid the one H2D
+            codes_dev = padded(codes if codes.dtype == jnp.int32 else codes.astype(jnp.int32))
+        else:
+            # the codes cross as int32; their padding is an eager device op
+            # of the same staging
+            with telemetry.span("h2d", bytes=4 * n):
+                codes_dev = padded(jnp.asarray(np.asarray(codes), dtype=jnp.int32))
+        if pad:
+            widths = [(0, 0)] * (arr.ndim - 1) + [(0, pad)]
+            arr = jnp.pad(arr, widths)
+        shard_len = codes_dev.shape[0] // ndev
+
+        # pad the group axis for psum_scatter ownership slicing
+        size_pad = size + _pad_to(n=size, multiple=ndev) if method == "cohorts" else size
+
+        spec_entry = axes if len(axes) > 1 else axes[0]
+        in_specs = (
+            P(*([None] * (arr.ndim - 1) + [spec_entry])),
+            P(spec_entry),
         )
-        # check_vma=False: outputs are replicated by construction (psum /
-        # all_gather), but the static checker cannot infer that through
-        # argmin/take_along_axis owner-selection.
-        fn = jax.jit(
-            shard_map(
-                program, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+        out_specs = P()  # replicated
+
+        cache_key = (
+            _agg_cache_key(agg), size, size_pad, method, axes, shard_len, nat,
+            mesh, arr.ndim, blocked, trace_fingerprint(),
+            None if cohort_perm is None else cohort_perm.tobytes(),
+        )
+        tm_on = telemetry.enabled()
+        if tm_on:
+            # cost-ledger baseline: dispatch wall + the jax-compile delta this
+            # mesh dispatch provokes (the build path's first run pays the
+            # trace+compile; the hit path should read ~0 compiles)
+            compiles0 = telemetry.METRICS.get("jax.compiles")
+            compile_ms0 = telemetry.METRICS.get("jax.compile_ms")
+            t_dispatch0 = perf_counter()
+
+        fn = _PROGRAM_CACHE.get(cache_key)
+        if fn is None:
+            telemetry.count("cache.program_misses")
+            program = _build_program(
+                agg, size=size, size_pad=size_pad, method=method, axis_name=axes,
+                shard_len=shard_len, nat=nat, cohort_perm=cohort_perm,
+                blocked=blocked, ndev=ndev,
             )
-        )
-        # bounded LRU: a cold key past capacity evicts ONE stale program
-        # (counted in cache.stats()["evictions"]), never the whole hot set
-        _PROGRAM_CACHE[cache_key] = fn
-        from ..profiling import timed
+            # check_vma=False: outputs are replicated by construction (psum /
+            # all_gather), but the static checker cannot infer that through
+            # argmin/take_along_axis owner-selection.
+            fn = jax.jit(
+                shard_map(
+                    program, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+                )
+            )
+            # bounded LRU: a cold key past capacity evicts ONE stale program
+            # (counted in cache.stats()["evictions"]), never the whole hot set
+            _PROGRAM_CACHE[cache_key] = fn
+            from ..profiling import timed
 
-        # jit/shard_map construction is lazy — trace + XLA compile happen
-        # on the first call, so THAT is what the build timer must wrap
-        with timed(f"sharded program trace+compile+first-run [{agg.name}/{method}]"):
-            with telemetry.span(
-                "program-build", agg=agg.name, method=method, ndev=ndev, size=size
-            ):
+            # jit/shard_map construction is lazy — trace + XLA compile happen
+            # on the first call, so THAT is what the build timer must wrap
+            with timed(f"sharded program trace+compile+first-run [{agg.name}/{method}]"):
+                with telemetry.span(
+                    "program-build", agg=agg.name, method=method, ndev=ndev, size=size
+                ):
+                    result = fn(arr, codes_dev)
+        else:
+            telemetry.count("cache.program_hits")
+            with telemetry.span("mesh-dispatch", agg=agg.name, method=method, ndev=ndev, size=size):
                 result = fn(arr, codes_dev)
-    else:
-        telemetry.count("cache.program_hits")
-        # the annotation makes the SPMD dispatch visible inside xprof device
-        # traces (jax.profiler.TraceAnnotation) as well as in our own trace
-        with telemetry.annotated(
-            f"flox:mesh-dispatch[{agg.name}/{method}]", ndev=ndev, size=size
-        ):
-            result = fn(arr, codes_dev)
     if tm_on:
         # observed wall snapshotted BEFORE the card analysis: its
         # lower+compile must not bill as device time (it would read as
@@ -680,7 +682,8 @@ def _build_program(
     count_skipna = skipna or agg.min_count > 0
 
     def finalize(combined, counts):
-        return _finalize_combined(agg, combined, counts)
+        with jax.named_scope("flox.finalize"):
+            return _finalize_combined(agg, combined, counts)
 
     def numpy_kernel(f, codes_sh, arr_sh, **extra):
         """Invoke one whole-reduction (agg.numpy) kernel — the SINGLE place
@@ -717,9 +720,11 @@ def _build_program(
         does not have: it forces method='blockwise' for order statistics
         (core.py:685-709)."""
         counts_local = _local_counts(codes_sh, arr_sh, size, count_skipna, nat)
-        counts = jax.lax.psum(counts_local, axis_name)
+        with jax.named_scope("flox.combine"):
+            counts = jax.lax.psum(counts_local, axis_name)
         result = numpy_kernel(agg.numpy[0], codes_sh, arr_sh, axis_name=axis_name)
-        return _apply_final_fill(result, counts, agg)
+        with jax.named_scope("flox.finalize"):
+            return _apply_final_fill(result, counts, agg)
 
     def mapreduce_program(arr_sh, codes_sh):
         if agg.reduction_type == "argreduce":
@@ -746,8 +751,9 @@ def _build_program(
             inters = [val, pos]
         else:
             counts_local, inters = local_stage(codes_sh, arr_sh)
-        counts = jax.lax.psum(counts_local, axis_name)
-        combined = _combine_intermediates(agg, inters, axis_name, nat)
+        with jax.named_scope("flox.combine"):
+            counts = jax.lax.psum(counts_local, axis_name)
+            combined = _combine_intermediates(agg, inters, axis_name, nat)
         return finalize(combined, counts)
 
     def blocked_cohorts_program(arr_sh, codes_sh):
@@ -765,33 +771,37 @@ def _build_program(
         def block(d):
             in_block = (codes_sh >= d * b) & (codes_sh < (d + 1) * b)
             bc = jnp.where(in_block, codes_sh - d * b, -1)
-            counts = jax.lax.psum(
-                _local_counts(bc, arr_sh, b, count_skipna, nat), axis_name
-            )
+            counts_local = _local_counts(bc, arr_sh, b, count_skipna, nat)
+            with jax.named_scope("flox.combine"):
+                counts = jax.lax.psum(counts_local, axis_name)
             outs = []
             for inter, op in zip(_local_chunk(agg, bc, arr_sh, b, nat), agg.combine):
-                outs.append(
-                    _combine_var(inter, axis_name)
-                    if op == "var"
-                    else _combine_simple(op, inter, axis_name, nat=nat and not skipna)
-                )
+                with jax.named_scope("flox.combine"):
+                    outs.append(
+                        _combine_var(inter, axis_name)
+                        if op == "var"
+                        else _combine_simple(op, inter, axis_name, nat=nat and not skipna)
+                    )
             return counts, outs
 
         c0, o0 = block(0)
         keep0 = me == 0
-        carry0 = jax.tree.map(lambda x: jnp.where(keep0, x, jnp.zeros_like(x)), (c0, o0))
+        with jax.named_scope("flox.combine"):
+            carry0 = jax.tree.map(lambda x: jnp.where(keep0, x, jnp.zeros_like(x)), (c0, o0))
 
         def body(d, carry):
             c, o = block(d)
             keep = me == d
-            return jax.tree.map(lambda new, acc: jnp.where(keep, new, acc), (c, o), carry)
+            with jax.named_scope("flox.combine"):
+                return jax.tree.map(lambda new, acc: jnp.where(keep, new, acc), (c, o), carry)
 
         counts_own, owned = jax.lax.fori_loop(1, ndev, body, carry0)
         result_own = finalize(owned, counts_own)
-        full = jax.lax.all_gather(
-            jnp.moveaxis(result_own, -1, 0), axis_name, tiled=True
-        )
-        return _crop(jnp.moveaxis(full, 0, -1), size)
+        with jax.named_scope("flox.combine"):
+            full = jax.lax.all_gather(
+                jnp.moveaxis(result_own, -1, 0), axis_name, tiled=True
+            )
+            return _crop(jnp.moveaxis(full, 0, -1), size)
 
     def cohorts_program(arr_sh, codes_sh):
         # psum_scatter needs every intermediate to be additive; route others
@@ -825,36 +835,38 @@ def _build_program(
             return _crop(full, size)
 
         counts_raw, inters = local_stage(codes_sh, arr_sh)
-        counts_local = to_slots(counts_raw)
-        counts_own = jax.lax.psum_scatter(
-            jnp.moveaxis(counts_local, -1, 0), axis_name, scatter_dimension=0, tiled=True
-        )
-        counts_own = jnp.moveaxis(counts_own, 0, -1)
+        with jax.named_scope("flox.combine"):
+            counts_local = to_slots(counts_raw)
+            counts_own = jax.lax.psum_scatter(
+                jnp.moveaxis(counts_local, -1, 0), axis_name, scatter_dimension=0, tiled=True
+            )
+            counts_own = jnp.moveaxis(counts_own, 0, -1)
 
-        owned = []
-        for inter, op in zip(inters, agg.combine):
-            if op == "var":
-                # scatter each leaf; the Chan adjustment needs the scattered
-                # totals, so do it leaf-wise after scattering sums
-                m2, total, nn = inter.arrays
-                mu_d = total / jnp.where(nn > 0, nn, 1)
-                big_t = _pscatter(to_slots(total), axis_name)
-                big_n = _pscatter(to_slots(nn), axis_name)
-                # mu over owned slice must be compared against each shard's
-                # mu_d — requires the adjustment before scattering:
-                # psum_scatter(m2 + n*(mu_d - mu)^2) with mu broadcast back.
-                mu = big_t / jnp.where(big_n > 0, big_n, 1)
-                mu_full = _unscatter_broadcast(mu, axis_name)
-                adj = nn * (mu_d - from_slots(mu_full)) ** 2
-                big_m2 = _pscatter(to_slots(m2 + adj), axis_name)
-                owned.append(MultiArray((big_m2, big_t, big_n)))
-            else:
-                owned.append(_pscatter(to_slots(inter), axis_name))
+            owned = []
+            for inter, op in zip(inters, agg.combine):
+                if op == "var":
+                    # scatter each leaf; the Chan adjustment needs the scattered
+                    # totals, so do it leaf-wise after scattering sums
+                    m2, total, nn = inter.arrays
+                    mu_d = total / jnp.where(nn > 0, nn, 1)
+                    big_t = _pscatter(to_slots(total), axis_name)
+                    big_n = _pscatter(to_slots(nn), axis_name)
+                    # mu over owned slice must be compared against each shard's
+                    # mu_d — requires the adjustment before scattering:
+                    # psum_scatter(m2 + n*(mu_d - mu)^2) with mu broadcast back.
+                    mu = big_t / jnp.where(big_n > 0, big_n, 1)
+                    mu_full = _unscatter_broadcast(mu, axis_name)
+                    adj = nn * (mu_d - from_slots(mu_full)) ** 2
+                    big_m2 = _pscatter(to_slots(m2 + adj), axis_name)
+                    owned.append(MultiArray((big_m2, big_t, big_n)))
+                else:
+                    owned.append(_pscatter(to_slots(inter), axis_name))
 
         result_own = finalize(owned, counts_own)
         # replicate: gather the owned slices back into the full group axis
-        full = jax.lax.all_gather(jnp.moveaxis(result_own, -1, 0), axis_name, tiled=True)
-        return from_slots(jnp.moveaxis(full, 0, -1))
+        with jax.named_scope("flox.combine"):
+            full = jax.lax.all_gather(jnp.moveaxis(result_own, -1, 0), axis_name, tiled=True)
+            return from_slots(jnp.moveaxis(full, 0, -1))
 
     def blockwise_program(arr_sh, codes_sh):
         counts_local = _local_counts(codes_sh, arr_sh, size, count_skipna, nat)
@@ -871,24 +883,30 @@ def _build_program(
             result_local = jnp.where(result_local >= 0, result_local + offset, -1)
         # owner = the shard that saw this group's elements (precondition:
         # exactly one, after reshard_for_blockwise)
-        counts_all = jax.lax.all_gather(counts_local, axis_name)  # (ndev, ..., size)
-        res_all = jax.lax.all_gather(result_local, axis_name)  # (ndev, *new, ..., size)
-        owner = jnp.argmax(counts_all > 0, axis=0)  # (..., size)
-        extra = res_all.ndim - 1 - owner.ndim  # new dims (e.g. quantile's q)
-        pick = jnp.broadcast_to(
-            owner.reshape((1,) * extra + owner.shape), res_all.shape[1:]
-        )
-        result = jnp.take_along_axis(res_all, pick[None], axis=0)[0]
-        counts = jax.lax.psum(counts_local, axis_name)
-        return _apply_final_fill(result, counts, agg)
+        with jax.named_scope("flox.combine"):
+            counts_all = jax.lax.all_gather(counts_local, axis_name)  # (ndev, ..., size)
+            res_all = jax.lax.all_gather(result_local, axis_name)  # (ndev, *new, ..., size)
+            owner = jnp.argmax(counts_all > 0, axis=0)  # (..., size)
+            extra = res_all.ndim - 1 - owner.ndim  # new dims (e.g. quantile's q)
+            pick = jnp.broadcast_to(
+                owner.reshape((1,) * extra + owner.shape), res_all.shape[1:]
+            )
+            result = jnp.take_along_axis(res_all, pick[None], axis=0)[0]
+            counts = jax.lax.psum(counts_local, axis_name)
+        with jax.named_scope("flox.finalize"):
+            return _apply_final_fill(result, counts, agg)
 
     if method == "map-reduce":
-        return orderstat_program if agg.blockwise_only else mapreduce_program
-    if method == "cohorts":
-        return cohorts_program
-    if method == "blockwise":
-        return blockwise_program
-    raise ValueError(f"Unknown method {method!r}")
+        program = orderstat_program if agg.blockwise_only else mapreduce_program
+    elif method == "cohorts":
+        program = cohorts_program
+    elif method == "blockwise":
+        program = blockwise_program
+    else:
+        raise ValueError(f"Unknown method {method!r}")
+    # the jitted program's name, which the profiler's trace shows
+    program.__name__ = program.__qualname__ = f"flox_mesh_{method.replace('-', '_')}"
+    return program
 
 
 def _pscatter(x, axis_name):

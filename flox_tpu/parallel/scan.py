@@ -104,8 +104,8 @@ def sharded_groupby_scan(
         _SCAN_CACHE[cache_key] = fn
     else:
         telemetry.count("cache.scan_hits")
-    with telemetry.annotated(
-        f"flox:mesh-scan[{scan.name}/{method}]", ndev=ndev, size=size
+    with telemetry.span(
+        "mesh-scan", scan=scan.name, method=method, ndev=ndev, size=size
     ):
         out = fn(arr, codes_dev)
     if pad:

@@ -32,7 +32,6 @@ logger = logging.getLogger("flox_tpu.profiling")
 
 __all__ = [
     "trace",
-    "annotate",
     "timed",
     "stream_monitor",
     "StreamReport",
@@ -264,13 +263,6 @@ def install_capture_signal() -> None:
         signal.signal(signum, _handler)
     except (ValueError, OSError):  # not the main thread / exotic platform
         return
-
-
-def annotate(name: str):
-    """Named region that shows up inside profiler traces."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)
 
 
 @contextlib.contextmanager

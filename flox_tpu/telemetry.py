@@ -87,6 +87,10 @@ import time
 from collections import deque
 from typing import Any, Iterable
 
+# a TraceAnnotation is jaxlib's TraceMe, whose is_enabled() says whether a
+# profiler session is collecting
+from jax.profiler import TraceAnnotation as _TraceMe
+
 __all__ = [
     "CANARY_TENANT",
     "FLIGHT_RECORDER",
@@ -96,7 +100,6 @@ __all__ = [
     "SATURATION_GAUGES",
     "MetricsRegistry",
     "anchor_event",
-    "annotated",
     "card_compile_accounting",
     "cost_by_dataset",
     "cost_by_program",
@@ -576,48 +579,65 @@ class _Span:
 def span(name: str, **attrs: Any):
     """Open a hierarchical span: ``with telemetry.span("factorize"): ...``.
 
-    Returns the shared no-op singleton when telemetry is disabled — no
-    allocation, no clock read. Nesting is tracked through a contextvar, so
-    spans opened on worker threads become roots of their own stacks (they
-    still interleave correctly by timestamp in the trace view).
+    While a ``jax.profiler`` session is collecting, the span also opens a
+    ``jax.profiler.TraceAnnotation`` named ``flox.<name>`` (its scalar
+    attributes as the event's arguments), whether telemetry is on or off:
+    the profiler stamps it on the clock it puts the device's ops on.
+
+    Returns the shared no-op singleton when telemetry is disabled and no
+    profiler session collects — no allocation, no clock read. Nesting is
+    tracked through a contextvar, so spans opened on worker threads become
+    roots of their own stacks (they still interleave correctly by timestamp
+    in the trace view).
     """
-    if not enabled():
-        return _NOOP
-    _bootstrap()
-    return _Span(name, attrs)
+    sp = _NOOP
+    if enabled():
+        _bootstrap()
+        sp = _Span(name, attrs)
+    if _TraceMe.is_enabled():
+        return _ProfiledSpan(sp, name, attrs)
+    return sp
 
 
-def annotated(name: str, **attrs: Any):
-    """A span that ALSO opens a ``jax.profiler.TraceAnnotation``, so the
-    region shows up inside xprof/TensorBoard device traces next to the XLA
-    ops it covers (the mesh dispatch paths use this). Falls back to a plain
-    span if the profiler API is unavailable."""
-    if not enabled():
-        return _NOOP
-    try:
-        import jax
-
-        annotation = jax.profiler.TraceAnnotation(name)
-    except Exception:  # noqa: BLE001 — profiling must never break execution
-        return span(name, **attrs)
-    return _AnnotatedSpan(span(name, **attrs), annotation)
+def _trace_args(attrs: dict) -> dict:
+    """The attributes a profiler event carries: numbers, bools and short
+    strings (lists, ``None`` and long text stay in the telemetry record)."""
+    return {
+        k: v for k, v in attrs.items()
+        if isinstance(v, (int, float)) or (isinstance(v, str) and len(v) <= 64)
+    }
 
 
-class _AnnotatedSpan:
-    __slots__ = ("_span", "_annotation")
+class _ProfiledSpan:
+    """A span while a profiler session collects: the telemetry span (the
+    no-op one when telemetry is off) inside a ``flox.<name>`` annotation."""
 
-    def __init__(self, sp: Any, annotation: Any) -> None:
+    __slots__ = ("_span", "_name", "_attrs", "_annotation")
+
+    def __init__(self, sp: Any, name: str, attrs: dict) -> None:
         self._span = sp
-        self._annotation = annotation
+        self._name = f"flox.{name}"
+        self._attrs = attrs
+        self._annotation: Any = None
 
-    def __enter__(self) -> Any:
+    def __enter__(self) -> "_ProfiledSpan":
         self._span.__enter__()
+        # a TraceMe's event starts when it is made, so it is made here
+        self._annotation = _TraceMe(self._name, **_trace_args(self._attrs))
         self._annotation.__enter__()
-        return self._span
+        return self
 
     def __exit__(self, *exc: Any) -> bool:
         self._annotation.__exit__(*exc)
         return self._span.__exit__(*exc)
+
+    def set(self, **attrs: Any) -> "_ProfiledSpan":
+        self._span.set(**attrs)
+        if self._annotation is None:
+            self._attrs.update(attrs)
+        elif _trace_args(attrs):
+            self._annotation.set_metadata(**_trace_args(attrs))
+        return self
 
 
 def record_span(

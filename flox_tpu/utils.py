@@ -86,7 +86,10 @@ def asarray_device(x: Any):
         # host -> device staging bytes (the streaming pipeline's device_put
         # counts its own in pipeline.SlabStager)
         telemetry.METRICS.inc("bytes.h2d", int(x.nbytes))
-    return jnp.asarray(x)
+    with telemetry.span("h2d") as h2d:
+        out = jnp.asarray(x)
+        h2d.set(bytes=int(out.nbytes))  # as staged: int64 codes cross as int32 without x64
+    return out
 
 
 def isnull(data: Any):
@@ -196,26 +199,29 @@ def map_row_blocks(fn, codes, array):
     kb = (ROW_BLOCK_BYTES // max(row_bytes, 1)) // 8 * 8
     if not kept or kb < 8 or k < 2 * kb:
         return fn(codes, array)
-    flat = array.reshape(k, n)
-    nfull = k // kb
     shapes = jax.eval_shape(fn, codes, jax.ShapeDtypeStruct((kb, n), array.dtype))
     if any(leaf.ndim < 2 or leaf.shape[-2] != kb for leaf in jax.tree.leaves(shapes)):
         return fn(codes, array)
+    # the block slices, the loop over them and the merge, as the profiler
+    # names them; fn's own ops keep their own scopes inside
+    with jax.named_scope("flox.rowblock"):
+        flat = array.reshape(k, n)
+        nfull = k // kb
 
-    def one(i):
-        return fn(codes, jax.lax.dynamic_slice_in_dim(flat, i * kb, kb, axis=0))
+        def one(i):
+            return fn(codes, jax.lax.dynamic_slice_in_dim(flat, i * kb, kb, axis=0))
 
-    stacked = jax.lax.map(one, jnp.arange(nfull))  # leaves (nfull, extra..., kb, size)
-    tail = fn(codes, flat[nfull * kb :]) if nfull * kb < k else None
+        stacked = jax.lax.map(one, jnp.arange(nfull))  # leaves (nfull, extra..., kb, size)
+        tail = fn(codes, flat[nfull * kb :]) if nfull * kb < k else None
 
-    def merge(leaf, tail_leaf):
-        ax = leaf.ndim - 3  # the block axis lands just before the kb axis
-        merged = jnp.moveaxis(leaf, 0, ax)
-        merged = merged.reshape(merged.shape[:ax] + (nfull * kb,) + merged.shape[ax + 2 :])
-        if tail_leaf is not None:
-            merged = jnp.concatenate([merged, tail_leaf], axis=ax)
-        return merged.reshape(merged.shape[:ax] + kept + merged.shape[ax + 1 :])
+        def merge(leaf, tail_leaf):
+            ax = leaf.ndim - 3  # the block axis lands just before the kb axis
+            merged = jnp.moveaxis(leaf, 0, ax)
+            merged = merged.reshape(merged.shape[:ax] + (nfull * kb,) + merged.shape[ax + 2 :])
+            if tail_leaf is not None:
+                merged = jnp.concatenate([merged, tail_leaf], axis=ax)
+            return merged.reshape(merged.shape[:ax] + kept + merged.shape[ax + 1 :])
 
-    if tail is None:
-        return jax.tree.map(lambda leaf: merge(leaf, None), stacked)
-    return jax.tree.map(merge, stacked, tail)
+        if tail is None:
+            return jax.tree.map(lambda leaf: merge(leaf, None), stacked)
+        return jax.tree.map(merge, stacked, tail)

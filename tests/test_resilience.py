@@ -833,6 +833,9 @@ class TestOptionValidation:
             "FLOX_TPU_STREAM_DONATE": ("stream_donate", "off", "maybe"),
         }
         saved = {k: os.environ.get(k) for k in probes}
+        # every module that imported OPTIONS holds the original object; a
+        # reload rebinds the module's names, so they are put back after it
+        module_names = dict(vars(options_mod))
         try:
             for env, (field, good, _bad) in probes.items():
                 os.environ[env] = str(good)
@@ -852,7 +855,7 @@ class TestOptionValidation:
                     os.environ.pop(k, None)
                 else:
                     os.environ[k] = v
-            importlib.reload(options_mod)
+            vars(options_mod).update(module_names)
 
 
 # ---------------------------------------------------------------------------

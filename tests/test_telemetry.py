@@ -148,10 +148,9 @@ class TestSpans:
         names = {r["name"] for r in telemetry.spans()}
         assert {"groupby_reduce", "factorize", "combine", "finalize"} <= names
         # first call builds the SPMD program, later ones hit the cache and
-        # dispatch under the annotated span — one of the two must be present
-        assert ("program-build" in names) or any(
-            n.startswith("flox:mesh-dispatch") for n in names
-        )
+        # dispatch under the mesh-dispatch span — one of the two must be present
+        assert {"dispatch", "h2d"} <= names
+        assert ("program-build" in names) or ("mesh-dispatch" in names)
 
     def test_scan_phases(self):
         with flox_tpu.set_options(telemetry=True):
@@ -197,6 +196,163 @@ class TestSpans:
                 lambda s, e: vals[:, s:e], codes, func="nansum", batch_len=16
             )
         assert not [r for r in telemetry.spans() if r["name"] == "stage"]
+
+
+# ---------------------------------------------------------------------------
+# the phase spans in a jax.profiler capture, with telemetry off
+# ---------------------------------------------------------------------------
+
+
+def _capture(fn, log_dir):
+    """``fn()`` under a profiler session, inside a ``caller`` annotation:
+    the host events named ``caller`` or ``flox.*`` as (name, start, end,
+    args), in start order."""
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(log_dir))
+    try:
+        with jax.profiler.TraceAnnotation("caller"):
+            jax.block_until_ready(fn())
+    finally:
+        jax.profiler.stop_trace()
+    (xplane,) = log_dir.glob("plugins/profile/*/*.xplane.pb")
+    events = []
+    for plane in ProfileData.from_file(str(xplane)).planes:
+        for line in plane.lines:
+            events += [
+                (e.name, e.start_ns, e.end_ns, dict(e.stats)) for e in line.events
+                if e.name == "caller" or e.name.startswith("flox.")
+            ]
+    return sorted(events, key=lambda ev: ev[1])
+
+
+class TestProfilerSpans:
+    PHASES = {"flox.factorize", "flox.dispatch", "flox.h2d", "flox.finalize"}
+
+    @pytest.mark.parametrize("call", ["reduce", "aggregate_many", "cohorts"])
+    def test_phases_in_capture_with_telemetry_off(self, call, tmp_path):
+        from flox_tpu.parallel.mesh import make_mesh
+
+        vals = np.random.default_rng(0).normal(size=(3, 48))
+        codes = np.arange(48) // 4 % 6
+        fns = {
+            "reduce": lambda: groupby_reduce(vals, codes, func="nanmean", engine="jax")[0],
+            "aggregate_many": lambda: flox_tpu.groupby_aggregate_many(
+                vals, codes, funcs=("mean", "max"), engine="jax")[0],
+            "cohorts": lambda: groupby_reduce(
+                vals, codes, func="nanmean", method="cohorts", mesh=make_mesh(4))[0],
+        }
+        events = _capture(fns[call], tmp_path)
+        names = {n for n, *_ in events}
+        assert self.PHASES <= names, sorted(names)
+        root = "flox.groupby_aggregate_many" if call == "aggregate_many" else "flox.groupby_reduce"
+        assert root in names
+        (_, c0, c1, _), = [ev for ev in events if ev[0] == "caller"]
+        for name, s, e, _ in events:
+            assert c0 <= s <= e <= c1, name  # inside the caller's own annotation
+        # the staging is a child of dispatch, and carries the bytes it staged
+        disp = [(s, e) for n, s, e, _ in events if n == "flox.dispatch"]
+        for n, s, e, args in events:
+            if n == "flox.h2d":
+                assert any(d0 <= s <= e <= d1 for d0, d1 in disp)
+                assert int(args["bytes"]) > 0
+        # telemetry stayed off: nothing recorded, no counter touched
+        assert telemetry.spans() == []
+        assert telemetry.METRICS.snapshot() == {}
+
+    def test_span_is_noop_outside_a_capture(self, tmp_path):
+        import jax
+
+        assert telemetry.span("factorize") is telemetry._NOOP
+        jax.profiler.start_trace(str(tmp_path))
+        try:
+            sp = telemetry.span("factorize", nby=1, funcs=["a"], note=None)
+            assert sp is not telemetry._NOOP
+            with sp as opened:
+                opened.set(ngroups=3)
+        finally:
+            jax.profiler.stop_trace()
+        assert telemetry.span("factorize") is telemetry._NOOP
+        assert telemetry.spans() == []
+
+    def test_scalar_attrs_only(self):
+        assert telemetry._trace_args(
+            {"n": 3, "x": 0.5, "ok": True, "s": "nanmean", "long": "x" * 65,
+             "funcs": ["a"], "none": None}
+        ) == {"n": 3, "x": 0.5, "ok": True, "s": "nanmean"}
+
+    def test_with_telemetry_on_records_and_annotates(self, tmp_path):
+        with flox_tpu.set_options(telemetry=True):
+            events = _capture(lambda: _run_reduce()[0], tmp_path)
+        assert self.PHASES <= {n for n, *_ in events}
+        records = {r["name"] for r in telemetry.spans()}
+        assert {"groupby_reduce", "factorize", "dispatch", "h2d", "finalize"} <= records
+
+
+class TestProgramScopes:
+    """The jitted programs' names and the ``flox.*`` scopes in their ops'
+    name stacks (the HLO's op_name metadata, which the chip's trace keeps)."""
+
+    @pytest.fixture
+    def small_blocks(self, monkeypatch):
+        from flox_tpu import utils
+
+        monkeypatch.setattr(utils, "ROW_BLOCK_BYTES", 48 * 8 * 8)  # rows of 48 f64 in blocks of 8
+        cache.clear_all()
+        yield
+        cache.clear_all()
+
+    @staticmethod
+    def _scopes(text):
+        import re
+
+        return set(re.findall(r"flox\.(?:kernel|rowblock|combine|finalize)", text))
+
+    def test_bundle_and_fused(self, small_blocks, monkeypatch):
+        import jax.numpy as jnp
+
+        from flox_tpu import core, fusion
+
+        built = []
+        real = core._jitted_bundle
+
+        def recording(*key):
+            built.append(real(*key))
+            return built[-1]
+
+        recording.cache_clear = real.cache_clear  # cache.clear_all calls it
+        monkeypatch.setattr(core, "_jitted_bundle", recording)
+        vals = np.random.default_rng(1).normal(size=(40, 48))
+        codes = np.arange(48) % 5
+        groupby_reduce(vals, codes, func="nanmean", engine="jax")
+        flox_tpu.groupby_aggregate_many(vals, codes, funcs=("mean", "var"), engine="jax")
+        args = (jnp.asarray(codes), jnp.asarray(vals))
+        (bundle,) = built
+        text = bundle.lower(*args).as_text(debug_info=True)
+        assert "@jit_flox_bundle" in text
+        assert {"flox.kernel", "flox.rowblock"} <= self._scopes(text)
+        (fused,) = fusion._FUSED_PROGRAM_CACHE.values()
+        text = fused.lower(*args).as_text(debug_info=True)
+        assert "@jit_flox_fused" in text
+        assert {"flox.kernel", "flox.rowblock", "flox.finalize"} <= self._scopes(text)
+
+    def test_mesh_program(self, small_blocks, monkeypatch):
+        import jax.numpy as jnp
+
+        from flox_tpu import utils
+        from flox_tpu.parallel import mapreduce
+        from flox_tpu.parallel.mesh import make_mesh
+
+        monkeypatch.setattr(utils, "ROW_BLOCK_BYTES", 12 * 8 * 8)  # a shard's rows are 12 f64
+
+        vals = np.random.default_rng(2).normal(size=(40, 48))
+        codes = np.arange(48) // 4 % 6
+        groupby_reduce(vals, codes, func="nanmean", method="cohorts", mesh=make_mesh(4))
+        (fn,) = [v for _, v in mapreduce._PROGRAM_CACHE.items()]
+        text = fn.lower(jnp.asarray(vals), jnp.asarray(codes, jnp.int32)).as_text(debug_info=True)
+        assert "@jit_flox_mesh_cohorts" in text
+        assert {"flox.kernel", "flox.rowblock", "flox.combine", "flox.finalize"} <= self._scopes(text)
 
 
 # ---------------------------------------------------------------------------

@@ -104,3 +104,23 @@ def test_kernel_compiles_for_v5e(one_chip, case, dtype):
     took = time.perf_counter() - t0
     assert "tpu_custom_call" in compiled.as_text()
     assert took < COMPILE_S_MAX, f"{case}: compile took {took:.1f} s"
+
+
+#: the kernel name each Pallas call gives its custom call, which the chip's
+#: trace shows as the op's name
+KERNEL_NAMES = {
+    "sum-smoke": "flox_segment_sum",
+    "minmax-smoke": "flox_minmax",
+    "multistat-smoke": "flox_multistat",
+    "nancumsum-smoke": "flox_segment_cumsum",
+    "radixbin-past-dense": "flox_radixbin",
+}
+
+
+@pytest.mark.parametrize("case", sorted(KERNEL_NAMES))
+def test_kernel_carries_its_name(one_chip, case):
+    fn, _, _, groups = CASES[case]
+    data = jax.ShapeDtypeStruct((1024, 256), jnp.float32, sharding=one_chip)
+    codes = jax.ShapeDtypeStruct((1024,), jnp.int32, sharding=one_chip)
+    text = jax.jit(lambda x, c: fn(x, c, groups)).lower(data, codes).as_text()
+    assert f'kernel_name = "{KERNEL_NAMES[case]}"' in text
