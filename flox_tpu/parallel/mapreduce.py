@@ -96,6 +96,62 @@ def _local_counts(codes_sh, arr_sh, size: int, skipna: bool, nat: bool):
     return generic_kernel(func, codes_sh, arr_sh, size=size, **kw)
 
 
+#: what the one-pass primitive leaves in a group no element reached, per leg
+_ONEPASS_EMPTY = {
+    "sum": 0, "nansum": 0, "len": 0, "nanlen": 0,
+    "min": np.inf, "nanmin": np.inf, "max": -np.inf, "nanmax": -np.inf,
+}
+
+
+def _local_stats(agg: Aggregation, codes_sh, arr_sh, size: int, count_skipna: bool, nat: bool):
+    """This shard's ``(counts, chunk intermediates)``: what
+    :func:`_local_counts` and :func:`_local_chunk` return, bit for bit and
+    dtype for dtype.
+
+    When the count and every chunk leg are legs ``kernels.fused_segment_stats``
+    serves, ONE pass computes them all: the sum kernel's NaN markers give
+    the non-NaN count as ``rowcount - nan_c``, so no (N, ...) int32 count
+    scatter runs. Where the primitive declines (int data, a scatter policy,
+    too many groups, a leg such as ``var_chunk``, counts alone) the per-leg
+    kernels run unchanged. A fused multi-statistic plan keeps its own
+    one-pass executor (``fused_chunk_stats``) beside the count kernel."""
+    import jax.numpy as jnp
+
+    from .. import telemetry
+    from ..aggregations import FusedAggregation
+    from ..kernels import _FUSABLE_LEG_NAMES, _acc_dtype, fused_segment_stats
+
+    legs = tuple(agg.chunk)
+    fills = tuple(agg.fill_value.get("intermediate", ())) + (None,) * len(legs)
+    count_name = "nanlen" if count_skipna else "len"
+    got = None
+    if (
+        not isinstance(agg, FusedAggregation)
+        and all(isinstance(leg, str) and leg in _FUSABLE_LEG_NAMES for leg in legs)
+        and all(fv is None or fv == _ONEPASS_EMPTY[leg] for leg, fv in zip(legs, fills))
+    ):
+        want = tuple(dict.fromkeys(legs + (count_name,)))
+        got = fused_segment_stats(codes_sh, arr_sh, size=size, want=want)
+    if got is None:
+        telemetry.count("mesh.local_perleg")
+        return (
+            _local_counts(codes_sh, arr_sh, size, count_skipna, nat),
+            _local_chunk(agg, codes_sh, arr_sh, size, nat),
+        )
+    telemetry.count("mesh.local_onepass")
+
+    def as_leg(name):
+        # the per-leg dtypes: int32 counts (exact: the primitive refuses
+        # N >= 2**24), sums in the accumulator (keep_acc), min/max in the data's
+        if name in ("len", "nanlen"):
+            return got[name].astype(jnp.int32)
+        if name in ("sum", "nansum"):
+            return got[name].astype(_acc_dtype(arr_sh.dtype))
+        return got[name].astype(arr_sh.dtype)
+
+    return as_leg(count_name), [as_leg(leg) for leg in legs]
+
+
 def _local_firstlast(codes_sh, arr_sh, size: int, *, skipna: bool, last: bool, nat: bool, offset):
     """(value, global position) per group for the first/last combine."""
     import jax
@@ -704,10 +760,7 @@ def _build_program(
         kept rows when the shard is large (``utils.map_row_blocks``), so
         their device temps stay bounded."""
         return utils.map_row_blocks(
-            lambda c, a: (
-                _local_counts(c, a, size, count_skipna, nat),
-                _local_chunk(agg, c, a, size, nat),
-            ),
+            lambda c, a: _local_stats(agg, c, a, size, count_skipna, nat),
             codes_sh, arr_sh,
         )
 
@@ -771,11 +824,11 @@ def _build_program(
         def block(d):
             in_block = (codes_sh >= d * b) & (codes_sh < (d + 1) * b)
             bc = jnp.where(in_block, codes_sh - d * b, -1)
-            counts_local = _local_counts(bc, arr_sh, b, count_skipna, nat)
+            counts_local, inters = _local_stats(agg, bc, arr_sh, b, count_skipna, nat)
             with jax.named_scope("flox.combine"):
                 counts = jax.lax.psum(counts_local, axis_name)
             outs = []
-            for inter, op in zip(_local_chunk(agg, bc, arr_sh, b, nat), agg.combine):
+            for inter, op in zip(inters, agg.combine):
                 with jax.named_scope("flox.combine"):
                     outs.append(
                         _combine_var(inter, axis_name)

@@ -846,8 +846,11 @@ def _slab_stats(agg: Aggregation, slab, ccodes, offset, *, size: int,
     import jax.numpy as jnp
 
     from .kernels import generic_kernel
-    from .parallel.mapreduce import _local_chunk, _local_counts
+    from .parallel.mapreduce import _local_counts, _local_stats
 
+    if agg.reduction_type != "argreduce" and agg.combine not in (("first",), ("last",)):
+        counts, inters = _local_stats(agg, ccodes, slab, size, count_skipna, nat)
+        return inters, counts
     skipna = agg.name.startswith("nan")
     kw = {"nat": True} if nat else {}
     counts = _local_counts(ccodes, slab, size, count_skipna, nat)
@@ -860,15 +863,13 @@ def _slab_stats(agg: Aggregation, slab, ccodes, offset, *, size: int,
         local_arg = generic_kernel(arg_f, ccodes, slab, size=size, fill_value=-1, **kw)
         gidx = jnp.where(local_arg >= 0, local_arg + offset, -1)
         return [val, gidx], counts
-    if agg.combine in (("first",), ("last",)):
-        from .parallel.mapreduce import _local_firstlast
+    from .parallel.mapreduce import _local_firstlast
 
-        val, pos = _local_firstlast(
-            ccodes, slab, size, skipna=skipna,
-            last=agg.combine == ("last",), nat=nat, offset=offset,
-        )
-        return [val, pos], counts
-    return _local_chunk(agg, ccodes, slab, size, nat), counts
+    val, pos = _local_firstlast(
+        ccodes, slab, size, skipna=skipna,
+        last=agg.combine == ("last",), nat=nat, offset=offset,
+    )
+    return [val, pos], counts
 
 
 def _merge_into(agg: Aggregation, state, inters, counts, *, nat: bool):
@@ -1085,8 +1086,7 @@ def _build_mesh_step_blocked(agg: Aggregation, *, size_pad: int, ndev: int,
         _combine_simple,
         _combine_var,
         _flat_axis_index,
-        _local_chunk,
-        _local_counts,
+        _local_stats,
     )
 
     spec_entry = axes if len(axes) > 1 else axes[0]
@@ -1101,11 +1101,10 @@ def _build_mesh_step_blocked(agg: Aggregation, *, size_pad: int, ndev: int,
         def block(d):
             in_block = (codes_sh >= d * b) & (codes_sh < (d + 1) * b)
             bc = jnp.where(in_block, codes_sh - d * b, -1)
-            counts = jax.lax.psum(
-                _local_counts(bc, slab_sh, b, count_skipna, nat), axes
-            )
+            counts_local, inters = _local_stats(agg, bc, slab_sh, b, count_skipna, nat)
+            counts = jax.lax.psum(counts_local, axes)
             outs = []
-            for inter, op in zip(_local_chunk(agg, bc, slab_sh, b, nat), agg.combine):
+            for inter, op in zip(inters, agg.combine):
                 outs.append(
                     _combine_var(inter, axes)
                     if op == "var"
