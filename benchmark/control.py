@@ -39,8 +39,6 @@ def main(argv: list[str] | None = None) -> int:
     except harness.NoChip as exc:
         print(f"control: {exc}; nothing was run", file=sys.stderr)
         return 2
-    funcs = traffic.funcs(cell.mix)
-    labels = data.labels(cell.config, cell.mix["labels"])
     program, control = [], []
     for i, seed in enumerate(args.seeds):
         x = data.make_array(cell.config, seed, devices)
@@ -49,9 +47,9 @@ def main(argv: list[str] | None = None) -> int:
         line = {"seed": seed, "program": harness.readings(cell, x, seed, [answered[0], answered[-1]])}
         program.append(line["program"])
         if i < args.control:
-            series = data.take_cells(x, data.sample_cells(cell.config, seed), columns=True)
-            groups, exact = reference.groupby(series, labels, funcs)
-            _, low = reference.groupby_bf16(series, labels, funcs)
+            _, series = harness.sampled_series(cell, x, seed)
+            groups, exact = harness.reference_of(cell, series)
+            _, low = harness.reference_of(cell, series, reference.groupby_bf16)
             line["control"] = compare.numbers(groups, low, groups, exact)
             control.append(line["control"])
         print(json.dumps(line), flush=True)

@@ -5,11 +5,25 @@ The array is made on the device in one jitted call, block by block in place
 chip makes its own shard. The values are ERA5-like 2 m temperature,
 280 + 10 N(0, 1) K, with NaN in every 17th grid cell at every 997th step
 from step 5 (as ``chip_smoke.make_data``), so NaN handling is exercised.
+
+A traffic mix names its labels. A name in the configuration's ``labels``
+table, ``{"<name>": {"source": "<module>", "dims": [...]}}``, is made by
+``labels/<module>.py``: its ``labels(config, spec)`` returns the label array
+over ``dims`` and the expected groups, or ``None`` for the labels' own
+unique values. Any other name is a field of the configuration's calendar
+over ``time``. The labels' dims are the array's trailing dims, the ones a
+call reduces; a "cell" is one flat index over the dims it keeps.
 """
 
 from __future__ import annotations
 
+import importlib.util
+from pathlib import Path
+
 import numpy as np
+
+#: where a configuration's label sources are found by name
+LABELS = Path(__file__).resolve().parent / "labels"
 
 #: largest block the generator fills at once: one ``random.normal`` call
 #: needs about twice its output
@@ -34,10 +48,46 @@ def calendar(config: dict):
     return pd.date_range(config["time_start"], periods=int(config["n_time"]), freq=config["time_freq"])
 
 
-def labels(config: dict, field: str) -> np.ndarray:
-    """The calendar field a user groups by (``time.month``, ``time.dayofyear``),
-    as the integer array ``.values`` gives."""
-    return np.asarray(getattr(calendar(config), field))
+def label_dims(config: dict, name: str) -> list[str]:
+    """The dims the labels ``name`` span: the table's, or ``time`` for a
+    calendar field. They have to be the array's trailing dims, since those
+    are what ``groupby_reduce``'s default ``axis`` reduces."""
+    spec = config.get("labels", {}).get(name)
+    dims = list(spec["dims"]) if spec else ["time"]
+    if not dims or list(config["dims"][-len(dims):]) != dims:
+        raise ValueError(f"labels {name!r} span {dims}, not trailing dims of the array's {config['dims']}")
+    return dims
+
+
+def _source(config: dict, name: str) -> tuple[np.ndarray, np.ndarray | None]:
+    """``(labels, expected groups or None)`` from the table's source."""
+    spec = config["labels"][name]
+    path = LABELS / f"{spec['source']}.py"
+    mod_spec = importlib.util.spec_from_file_location(f"benchmark.labels.{spec['source']}", path)
+    mod = importlib.util.module_from_spec(mod_spec)
+    mod_spec.loader.exec_module(mod)
+    values, expected = mod.labels(config, spec)
+    values = np.asarray(values)
+    want = tuple(int(config["n_" + d]) for d in label_dims(config, name))
+    if values.shape != want:
+        raise ValueError(f"{path} made labels {name!r} of shape {values.shape}; their dims are {want}")
+    return values, None if expected is None else np.asarray(expected)
+
+
+def labels(config: dict, name: str) -> np.ndarray:
+    """The labels a user groups by, over their dims: a calendar field
+    (``time.month``, ``time.dayofyear``) as the integer array ``.values``
+    gives, or what the configuration's source for ``name`` makes."""
+    if name not in config.get("labels", {}):
+        return np.asarray(getattr(calendar(config), name))
+    return _source(config, name)[0]
+
+
+def expected_groups(config: dict, name: str) -> np.ndarray | None:
+    """The groups a call is told to expect, where the source gives them."""
+    if name not in config.get("labels", {}):
+        return None
+    return _source(config, name)[1]
 
 
 def _block_rows(local: tuple[int, ...], itemsize: int) -> int:
@@ -107,25 +157,32 @@ def make_array(config: dict, seed: int, devices):
     return gen(np.asarray(seed_words(seed), dtype=np.uint32)).block_until_ready()
 
 
-def sample_cells(config: dict, seed: int, budget_bytes: int = 2**30) -> np.ndarray:
-    """Grid cells (flat indices over every dimension but time) whose answers
-    are compared with the reference: drawn from the seed, as many as
-    ``budget_bytes`` of their float32 series hold, sorted."""
+def sample_cells(config: dict, seed: int, budget_bytes: int = 2**30, reduced: int = 1) -> np.ndarray:
+    """Cells (flat indices over the dims a call keeps, all but the last
+    ``reduced``) whose answers are compared with the reference: drawn from
+    the seed, as many as ``budget_bytes`` of their series hold, sorted."""
     full = shape(config)
-    ncell = int(np.prod(full[:-1]))
-    n = min(ncell, max(1, budget_bytes // (full[-1] * 4)))
+    ncell = int(np.prod(full[:-reduced]))
+    per_cell = int(np.prod(full[-reduced:])) * np.dtype(config["dtype"]).itemsize
+    n = min(ncell, max(1, budget_bytes // per_cell))
     rng = np.random.default_rng([int(seed), 1])
     return np.sort(rng.choice(ncell, n, replace=False))
 
 
-def take_cells(a, cells: np.ndarray, columns: bool = False) -> np.ndarray:
-    """``a``'s values at the flat grid ``cells``, on the host:
-    (len(cells), last), or with ``columns`` (last, len(cells))."""
+def gather(v, i, columns: bool = False, reduced: int = 1):
+    """``v``'s series at the flat cells ``i`` (traced; see :func:`take_cells`)."""
+    rows = v.reshape(-1, int(np.prod(v.shape[-reduced:])))[i]
+    return rows.T if columns else rows
+
+
+def take_cells(a, cells: np.ndarray, columns: bool = False, reduced: int = 1) -> np.ndarray:
+    """``a``'s series at the flat ``cells``, on the host, each over its last
+    ``reduced`` dims flattened: (len(cells), series), or with ``columns``
+    (series, len(cells))."""
+    import functools
+
     import jax
     import jax.numpy as jnp
 
-    def gather(v, i):
-        rows = v.reshape(-1, v.shape[-1])[i]
-        return rows.T if columns else rows
-
-    return np.asarray(jax.device_get(jax.jit(gather)(a, jnp.asarray(cells, dtype=jnp.int32))))
+    taken = jax.jit(functools.partial(gather, columns=columns, reduced=reduced))(a, jnp.asarray(cells, dtype=jnp.int32))
+    return np.asarray(jax.device_get(taken))

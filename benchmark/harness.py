@@ -2,7 +2,8 @@
 reference, and the result line.
 
 Everything a cell needs is found by name: its configuration and traffic mix
-from ``BENCHMARK.json``, each metric's reader in ``metrics/<name>.py``.
+from ``BENCHMARK.json``, its labels' source in ``labels/<module>.py`` where
+the configuration names one, each metric's reader in ``metrics/<name>.py``.
 """
 
 from __future__ import annotations
@@ -13,8 +14,10 @@ import math
 import os
 import sys
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from pathlib import Path
+
+import numpy as np
 
 from . import compare, data, reference, trace, traffic
 
@@ -24,6 +27,9 @@ TRACE_DIR = HERE / ".trace"
 #: a traced run skips this many steps, then traces at least TRACE_STEPS
 #: steps and TRACE_SECONDS seconds (or the whole --seconds, if shorter)
 TRACE_SKIP, TRACE_STEPS, TRACE_SECONDS = 2, 3, 2.0
+#: calls a traced run makes with flox_tpu's telemetry on, once the traced
+#: stretch has ended: the first builds its programs again, the second does not
+COUNTED_CALLS = 2
 
 
 class NoChip(RuntimeError):
@@ -50,6 +56,7 @@ class Cell:
         configs = {c["name"]: c for c in bench["configs"]}
         config = json.loads((ROOT / configs[w["config"]]["file"]).read_text())
         mix = traffic.load(HERE / "traffic" / f"{w['traffic']}.json")
+        data.label_dims(config, mix["labels"])  # raises where they are not the array's trailing dims
         e2e = [m for m in bench["end_to_end"] if name in m.get("workloads", [name])]
         if not traced:
             metrics = e2e
@@ -74,6 +81,10 @@ class Run:
     output_bytes: int
     peak: dict
     trace: trace.Trace | None = None
+    #: flox_tpu's telemetry registry (counters, and gauges): name -> its
+    #: change in each call of a traced run's counted stretch (``counted``);
+    #: empty in an untraced run
+    counters: dict[str, list[float]] = field(default_factory=dict)
 
     @property
     def window_s(self) -> float:
@@ -198,20 +209,56 @@ def _loop(call, seconds: float, min_steps: int = 1, annotate: bool = False):
             return steps, first, last, errors
 
 
+def counted(call, calls: int = COUNTED_CALLS) -> dict[str, list[float]]:
+    """The change of each of flox_tpu's telemetry counters (and gauges) in
+    each of ``calls`` calls made with telemetry on. The program caches are cleared first, so the first call
+    builds its programs again and what the program counts as it builds them
+    (``mesh.local_onepass``) shows beside what it counts on every call."""
+    import flox_tpu
+    from flox_tpu import cache, telemetry
+
+    cache.clear_all()
+    out: dict[str, list[float]] = {}
+    with flox_tpu.set_options(telemetry=True):
+        for i in range(calls):
+            before = telemetry.METRICS.snapshot()
+            call()
+            for name, value in telemetry.METRICS.snapshot().items():
+                if value != before.get(name, 0):
+                    out.setdefault(name, [0.0] * calls)[i] = float(value - before.get(name, 0))
+    return out
+
+
 def memory_peak(devices) -> int:
     stats = [d.memory_stats() or {} for d in devices]
     return max(int(s.get("peak_bytes_in_use", 0)) for s in stats)
 
 
+def sampled_series(cell: Cell, x, seed: int) -> tuple[np.ndarray, np.ndarray]:
+    """``(cells, series)``: the seed's sample of the cells a call keeps, and
+    their series over the reduced dims as the columns of one array."""
+    reduced = len(data.label_dims(cell.config, cell.mix["labels"]))
+    cells = data.sample_cells(cell.config, seed, reduced=reduced)
+    return cells, data.take_cells(x, cells, columns=True, reduced=reduced)
+
+
+def reference_of(cell: Cell, series: np.ndarray, groupby=reference.groupby) -> tuple[np.ndarray, dict]:
+    """``groupby`` over ``series`` by the cell's labels, its answers laid
+    out over the expected groups where the configuration gives them."""
+    name = cell.mix["labels"]
+    groups, answers = groupby(series, data.labels(cell.config, name).ravel(), traffic.funcs(cell.mix))
+    expected = data.expected_groups(cell.config, name)
+    return (groups, answers) if expected is None else reference.over(expected, groups, answers)
+
+
 def readings(cell: Cell, x, seed: int, answered: list) -> dict[str, float]:
     """The compared numbers of the answers of the window's first and last
-    calls, at the seed's sample of grid cells, against the float64 reference
+    calls, at the seed's sample of cells, against the float64 reference
     (each number's worst over the calls)."""
     t0 = time.perf_counter()
     funcs = traffic.funcs(cell.mix)
-    cells = data.sample_cells(cell.config, seed)
-    series = data.take_cells(x, cells, columns=True)
-    ref_groups, ref = reference.groupby(series, data.labels(cell.config, cell.mix["labels"]), funcs)
+    cells, series = sampled_series(cell, x, seed)
+    ref_groups, ref = reference_of(cell, series)
     got = [
         compare.numbers(groups, {f: data.take_cells(answers[f], cells) for f in funcs if f in answers},
                         ref_groups, ref)
@@ -257,6 +304,12 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, devices, age=proces
         steps, first, last, errors = _loop(call, seconds)
     compiles, _ = counter.reset()
     peak = memory_peak(devices)
+    counters = {}
+    if traced:
+        counters = counted(call)
+        built, hits = counter.reset()
+        log(f"counters: {COUNTED_CALLS} calls with telemetry on from cleared program caches, "
+            f"{built} programs built, {hits} of them from the persistent cache; {counters}")
     kind = devices[0].device_kind
     ok = [s for s in steps if s[2]]
     ms = sorted((e - s) * 1e3 for s, e, _ in steps)
@@ -270,7 +323,8 @@ def run(cell: Cell, seed: int, seconds: float, traced: bool, devices, age=proces
     for e in errors[:5]:
         print(f"failed call: {e}", file=sys.stderr, flush=True)
 
-    r = Run(cell, setup_s, steps, in_bytes, out_bytes, peaks(kind) if devices[0].platform == "tpu" else {}, tr)
+    r = Run(cell, setup_s, steps, in_bytes, out_bytes, peaks(kind) if devices[0].platform == "tpu" else {}, tr,
+            counters)
     metrics = {}
     for m in cell.metrics:
         value = reader(m["name"])(r)

@@ -12,10 +12,11 @@ host-to-device staging, with its ``bytes``), ``flox.combine``,
 into each op's name stack, which the TPU's trace keeps as the ``tf_op`` stat
 of the op's event metadata (the CPU's trace does not carry it).
 
-``trace.load`` keeps neither, and ``jax.profiler.ProfileData`` does not show
-event metadata, so :func:`load` reads the same ``.xplane.pb`` through a
-minimal schema of its protobuf. A :class:`Program` sits beside a
-``trace.Trace`` and is joined to its ops by start and name.
+``jax.profiler.ProfileData`` does not show event metadata, so :func:`load`
+reads the same ``.xplane.pb`` through a minimal schema of its protobuf.
+``trace.load`` keeps its :class:`Program` as ``Trace.program``, joined to the
+trace's ops by start and name; the readers in ``metrics/`` call this
+module's numbers on ``align(trace, trace.program)``.
 
     python3 -m benchmark.program_trace --workload <name> --seed <n> [--seconds <s>]
 
@@ -445,19 +446,18 @@ def main(argv: list[str] | None = None) -> int:
     read = trace.load
     kept = {}
 
-    def read_both(xplane, chips):
-        kept["program"] = load(xplane, chips)
+    def keep(xplane, chips):
         kept["trace"] = read(xplane, chips)
         return kept["trace"]
 
-    trace.load = read_both  # the harness deletes the file once read
+    trace.load = keep  # the harness deletes the file once read
     try:
         result = harness.run(cell, args.seed, args.seconds, True, devices)
     finally:
         trace.load = read
     harness.emit(result)
-    tr, prog = kept["trace"], kept["program"]
-    print(json.dumps({"program": numbers(tr, prog), "breakdown": breakdown(tr, prog)}), flush=True)
+    tr = kept["trace"]
+    print(json.dumps({"program": numbers(tr, tr.program), "breakdown": breakdown(tr, tr.program)}), flush=True)
     return 0
 
 

@@ -77,3 +77,24 @@ def groupby_bf16(series: np.ndarray, labels: np.ndarray, funcs) -> tuple[np.ndar
     import ml_dtypes
 
     return groupby(series.astype(ml_dtypes.bfloat16).astype(np.float32), labels, funcs)
+
+
+def fill(func: str) -> float:
+    """A statistic's answer for a group with no member: 0 for ``count`` and
+    ``sum``, NaN for the others."""
+    return 0.0 if func.removeprefix("nan") in ("count", "sum") else np.nan
+
+
+def over(expected: np.ndarray, groups: np.ndarray, answers: dict) -> tuple[np.ndarray, dict]:
+    """``groupby``'s answers laid out over the ``expected`` groups, each one
+    that no label names given its statistic's :func:`fill`."""
+    expected = np.asarray(expected)
+    order = np.argsort(expected, kind="stable")
+    pos = order[np.minimum(np.searchsorted(expected, groups, sorter=order), expected.size - 1)]
+    if not np.array_equal(expected[pos], groups):
+        raise ValueError("the labels name groups that are not among the expected ones")
+    out = {}
+    for f, a in answers.items():
+        out[f] = np.full((a.shape[0], expected.size), fill(f))
+        out[f][:, pos] = a
+    return expected, out

@@ -9,6 +9,10 @@ line: one interval per operation the chip ran, a wait for an asynchronous
 copy or collective included (its ``-done`` op). An op that holds others
 (a ``while`` around its body) counts in the busy time but not in the
 breakdown, which lists the ops that did the work.
+
+``load`` also reads the program's own spans and scopes from the same file
+(``program_trace.Program``, as ``Trace.program``); a reader puts them on the
+host's clock with ``program_trace.align(trace, trace.program)``.
 """
 
 from __future__ import annotations
@@ -17,6 +21,10 @@ import re
 import statistics
 from dataclasses import dataclass, field
 from pathlib import Path
+from typing import TYPE_CHECKING
+
+if TYPE_CHECKING:
+    from .program_trace import Program
 
 STEP, CALL, WAIT = "bench.step", "bench.call", "bench.wait"
 SPANS = (STEP, CALL, WAIT)
@@ -34,12 +42,14 @@ COLLECTIVE = re.compile(r"all-reduce|all-gather|reduce-scatter|collective-permut
 class Trace:
     """``ops``: device id -> sorted ``(start_ns, end_ns, name)`` of the ops
     that hold no other op; ``outer``: the same for ops that do;
-    ``spans``: sorted ``(start_ns, end_ns, name)`` of the benchmark's spans."""
+    ``spans``: sorted ``(start_ns, end_ns, name)`` of the benchmark's spans;
+    ``program``: the program's spans and scopes, where they were read."""
 
     ops: dict[int, list[tuple[int, int, str]]]
     spans: list[tuple[int, int, str]]
     outer: dict[int, list[tuple[int, int, str]]] = field(default_factory=dict)
     merged: dict[int, list[tuple[int, int]]] = field(default_factory=dict)
+    program: Program | None = None
 
     def __post_init__(self):
         self.merged = {
@@ -57,8 +67,11 @@ class Trace:
 
 
 def load(xplane: Path, chips: int) -> Trace:
-    """Read an ``.xplane.pb``: the ops of TPU devices 0..chips-1, and the spans."""
+    """Read an ``.xplane.pb``: the ops of TPU devices 0..chips-1, the spans,
+    and the program's spans and scopes."""
     from jax.profiler import ProfileData
+
+    from . import program_trace
 
     prof = ProfileData.from_file(str(xplane))
     ops: dict[int, list] = {}
@@ -73,7 +86,8 @@ def load(xplane: Path, chips: int) -> Trace:
             elif not m:
                 spans.extend((int(e.start_ns), int(e.end_ns), e.name) for e in line.events if e.name in SPANS)
     split = {d: split_outer(v) for d, v in ops.items()}
-    return Trace({d: v[0] for d, v in split.items()}, sorted(spans), {d: v[1] for d, v in split.items()})
+    return Trace({d: v[0] for d, v in split.items()}, sorted(spans), {d: v[1] for d, v in split.items()},
+                 program=program_trace.load(xplane, chips))
 
 
 def split_outer(events) -> tuple[list, list]:

@@ -2,9 +2,11 @@
 loop makes, and the logical bytes that call moves.
 
 A mix names a public entry point of flox_tpu (``entry``), the keyword
-arguments a user passes (``kwargs``), the calendar field the array is
-grouped by (``labels``) and whether the call runs over the cell's chips as
-a mesh (``mesh``). ``limits`` holds the comparison's limits.
+arguments a user passes (``kwargs``), the labels the array is grouped by
+(``labels``: a calendar field, or a name the configuration's ``labels``
+table gives a source; see ``data``) and whether the call runs over the
+cell's chips as a mesh (``mesh``). ``limits`` holds the comparison's
+limits.
 """
 
 from __future__ import annotations
@@ -41,6 +43,9 @@ def make_call(mix: dict, config: dict, array, devices):
     entry = getattr(flox_tpu, mix["entry"])
     by = data.labels(config, mix["labels"])
     kwargs = dict(mix["kwargs"])
+    expected = data.expected_groups(config, mix["labels"])
+    if expected is not None:
+        kwargs["expected_groups"] = expected
     if mix.get("mesh"):
         from flox_tpu.parallel.mesh import make_mesh
 
@@ -59,12 +64,18 @@ def make_call(mix: dict, config: dict, array, devices):
 
 def logical_bytes(config: dict, mix: dict, ngroups: int) -> tuple[int, int]:
     """``(input, output)`` bytes one call needs at the least: the input read
-    once and each statistic's answers written once, whatever it computes."""
+    once and each statistic's answers written once (one per kept cell and
+    group), whatever it computes."""
     shape = data.shape(config)
     itemsize = np.dtype(config["dtype"]).itemsize
-    cells = int(np.prod(shape[:-1]))
+    cells = int(np.prod(shape[: -len(data.label_dims(config, mix["labels"]))]))
     return int(np.prod(shape)) * itemsize, cells * ngroups * itemsize * len(funcs(mix))
 
 
 def ngroups(config: dict, mix: dict) -> int:
+    """The groups a call answers: the expected ones where the configuration
+    gives them, else the labels' unique values."""
+    expected = data.expected_groups(config, mix["labels"])
+    if expected is not None:
+        return int(expected.size)
     return int(np.unique(data.labels(config, mix["labels"])).size)

@@ -112,3 +112,50 @@ def test_fault_under_the_timed_path_is_not_correct(name, fault, monkeypatch):
     _broken(monkeypatch, cell.mix["entry"], fault)
     result = run(cell)
     assert not result["correct"], result["checks"]
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_traced_run_is_correct(name):
+    """A ``--trace 1`` run: the same comparison, the per-layer metrics that
+    a CPU trace can give, and the device's traced window."""
+    import jax
+
+    cell = tiny(name, traced=True)
+    result = harness.run(cell, SEED, SECONDS, True, jax.devices()[: cell.chips], age=lambda: 0.0)
+    assert result["correct"], result["checks"]
+    assert "host.factorize_ms" in result["metrics"]
+    assert set(result["metrics"]) <= {m["name"] for m in cell.metrics}
+    assert result["device"]["window_s"] > 0 and set(result["breakdown"]) == {"device_ops", "idle_gaps"}
+
+
+def test_counters_come_from_calls_after_the_traced_stretch(monkeypatch):
+    """``Run.counters`` carries ``mesh.local_onepass``, which the program
+    counts as it builds the mesh's local stage (the Pallas sum kernel, in
+    interpret mode here), from the calls a traced run makes with telemetry
+    on once its traced stretch has ended; every call before them ran with
+    telemetry off."""
+    import jax
+
+    from flox_tpu.options import OPTIONS
+
+    cell = tiny("era5_hourly_025_x4.doy_cohorts_nanmean", traced=True)
+    telemetry_at_call = []
+    real = flox_tpu.groupby_reduce
+
+    def spy(*args, **kw):
+        telemetry_at_call.append(OPTIONS["telemetry"])
+        return real(*args, **kw)
+
+    monkeypatch.setattr(flox_tpu, "groupby_reduce", spy)
+    runs = []
+    read = harness.reader
+    monkeypatch.setattr(harness, "reader", lambda n: lambda run: runs.append(run) or read(n)(run))
+    with flox_tpu.set_options(telemetry=False, segment_sum_impl="pallas"):
+        result = harness.run(cell, SEED, SECONDS, True, jax.devices()[: cell.chips], age=lambda: 0.0)
+    assert result["correct"], result["checks"]
+    counters = runs[0].counters
+    assert counters["mesh.local_onepass"][0] >= 1 and "mesh.local_perleg" not in counters
+    assert counters["cache.program_misses"] == [1.0, 0.0] and counters["cache.program_hits"] == [0.0, 1.0]
+    assert result["metrics"]["mesh.onepass_share"]["value"] == 100.0
+    n = harness.COUNTED_CALLS
+    assert telemetry_at_call[-n:] == [True] * n and not any(telemetry_at_call[:-n])

@@ -221,3 +221,59 @@ def test_load_of_a_cpu_capture(tmp_path):
     assert pt.factorize_ms(tr, prog) > 0
     assert pt.h2d_bytes(tr, prog) == codes_bytes
     assert pt.glue_share(tr, prog) is None
+
+
+def _xspace_of(tr, prog) -> bytes:
+    """The hand-built trace and program as a serialized XSpace: one TPU plane
+    (each op with its name stack) and one host plane (the benchmark's spans
+    on one line, the program's on another)."""
+    space = pt._schema()()
+    dev = space.planes.add(name=b"/device:TPU:0")
+    dev.stat_metadata.add(key=1, value={"name": b"tf_op"})
+    ops = dev.lines.add(name=b"XLA Ops", timestamp_ns=0)
+    names = {}
+    for s, e, n in tr.ops[0]:
+        if n not in names:
+            names[n] = len(names) + 10
+            md = dev.event_metadata.add(key=names[n], value={"name": n.encode()})
+            if prog.scope(0, (s, e, n)):  # an op with no op name has no name stack
+                md.value.stats.add(metadata_id=1, str_value=prog.scope(0, (s, e, n)).encode())
+        ops.events.add(metadata_id=names[n], offset_ps=s * 1000, duration_ps=(e - s) * 1000)
+    host = space.planes.add(name=b"/host:CPU")
+    host.stat_metadata.add(key=3, value={"name": b"bytes"})
+    keys = {}
+    for line_name, spans in ((b"bench", [(s, e, n, {}) for s, e, n in tr.spans]), (b"flox", prog.spans)):
+        line = host.lines.add(name=line_name, timestamp_ns=0)
+        for s, e, n, args in spans:
+            if n not in keys:
+                keys[n] = len(keys) + 20
+                host.event_metadata.add(key=keys[n], value={"name": n.encode()})
+            stats = [{"metadata_id": 3, "int64_value": int(args["bytes"])}] if "bytes" in args else []
+            line.events.add(metadata_id=keys[n], offset_ps=s * 1000, duration_ps=(e - s) * 1000, stats=stats)
+    return space.SerializeToString()
+
+
+def test_readers_of_the_program_through_trace_load(tmp_path):
+    """``trace.load`` keeps the program's spans and scopes as
+    ``Trace.program``; the four readers take their numbers from it."""
+    tr, prog = _trace()
+    path = tmp_path / "t.xplane.pb"
+    path.write_bytes(_xspace_of(tr, prog))
+    loaded = trace.load(path, chips=1)
+    assert loaded.ops == tr.ops and loaded.spans == tr.spans
+    assert [sp[:3] for sp in loaded.program.spans] == [sp[:3] for sp in prog.spans]
+    assert loaded.program.scopes == prog.scopes
+    cell = harness.Cell("c", 1, {}, {}, [])
+    run = harness.Run(cell, 1.0, [(0.0, 0.01, True), (0.01, 0.02, True)], 8 * 10**6, 2 * 10**6,
+                      {"hbm_gbps": 1000.0}, loaded)
+    read = {n: harness.reader(n)(run) for n in
+            ("host.factorize_ms", "host.blocking_ms", "kernels.glue_share", "mesh.combine_share")}
+    assert read == {"host.factorize_ms": pytest.approx(0.4), "host.blocking_ms": pytest.approx(1.8),
+                    "kernels.glue_share": pytest.approx(100 * (1 - 8 / 12)),
+                    "mesh.combine_share": pytest.approx(100 / 12)}
+    # without the program's spans and scopes, or untraced, they read nothing
+    bare = harness.Run(cell, 1.0, run.steps, 8 * 10**6, 2 * 10**6, {"hbm_gbps": 1000.0}, tr)
+    untraced = harness.Run(cell, 1.0, run.steps, 8 * 10**6, 2 * 10**6, {"hbm_gbps": 1000.0})
+    for name in read:
+        assert harness.reader(name)(bare) is None
+        assert harness.reader(name)(untraced) is None
