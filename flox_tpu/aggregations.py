@@ -178,6 +178,21 @@ class Aggregation:
             return ()
         return self.new_dims_func(**self.finalize_kwargs)
 
+    def without_appended_count(self) -> "Aggregation":
+        """This aggregation without the ``nanlen`` leg that ``min_count``
+        appended. The runtimes that carry counts of their own (the mesh
+        programs, streaming, the span-blocked path, a fused plan) mask by
+        those; left in, the leg reaches ``finalize`` as a stray positional
+        argument (var's ddof became a count array)."""
+        if not self.appended_count:
+            return self
+        agg = copy.deepcopy(self)
+        agg.chunk = agg.chunk[:-1]
+        agg.combine = agg.combine[:-1]
+        agg.fill_value["intermediate"] = agg.fill_value["intermediate"][:-1]
+        agg.appended_count = False
+        return agg
+
 
 # --- finalize helpers -------------------------------------------------------
 
@@ -635,16 +650,11 @@ def plan_fused(
             f, per_func(dtype, f), array_dtype, per_func(fill_value, f),
             min_count, per_func(finalize_kwargs, f) or {},
         )
-        if agg.appended_count:
-            # the fused plan carries ONE shared nanlen leg for min_count;
-            # the per-agg appended count would otherwise mask the combine
-            # signature (var's ("var",) becomes ("var", "sum")) and
-            # misclassify the Chan triple below
-            agg.chunk = agg.chunk[:-1]
-            agg.combine = agg.combine[:-1]
-            agg.fill_value["intermediate"] = agg.fill_value["intermediate"][:-1]
-            agg.appended_count = False
-        aggs.append(agg)
+        # the fused plan carries ONE shared nanlen leg for min_count; the
+        # per-agg appended count would otherwise mask the combine signature
+        # (var's ("var",) becomes ("var", "sum")) and misclassify the Chan
+        # triple below
+        aggs.append(agg.without_appended_count())
     aggs = tuple(aggs)
 
     legs: list[dict] = []  # {"entry", "combine", "fill", "eager_dtype"}

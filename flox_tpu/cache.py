@@ -210,7 +210,7 @@ def clear_all() -> None:
     same)."""
     from .autotune import _AUTOTUNE_CACHE, _AUTOTUNE_STATE
     from .cohorts import _COHORTS_CACHE
-    from .core import _jitted_bundle
+    from .core import _SPAN_PROGRAMS, _jitted_bundle
     from .costmodel import _CARD_LABELS, _CARD_REGISTRY
     from .factorize import _FACTORIZE_CACHE, _FACTORIZE_CACHE_BYTES
     from .fusion import _FUSED_PROGRAM_CACHE
@@ -243,6 +243,7 @@ def clear_all() -> None:
     _FACTORIZE_CACHE.clear()
     _FACTORIZE_CACHE_BYTES[0] = 0
     _PROGRAM_CACHE.clear()
+    _SPAN_PROGRAMS.clear()
     _SCAN_CACHE.clear()
     _STEP_CACHE.clear()
     _FUSED_PROGRAM_CACHE.clear()

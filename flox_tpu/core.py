@@ -30,6 +30,7 @@ import pandas as pd
 
 from . import dtypes, factorize as fct, telemetry, utils
 from .aggregations import Aggregation, _initialize_aggregation, generic_aggregate, normalize_engine
+from .cache import LRUCache
 from .options import OPTIONS
 
 logger = logging.getLogger("flox_tpu.core")
@@ -754,8 +755,14 @@ def _groupby_reduce_impl(
     nred = int(np.prod(nred_shape)) if nred_shape else 1
     span = int(np.prod(keep_by_shape + nred_shape)) if (keep_by_shape or nred_shape) else 1
     lead_shape = arr.shape[: arr.ndim - bndim]
-    arr_flat = arr.reshape(lead_shape + (span,))
     codes_flat = np.asarray(codes).reshape(-1)
+    span_rows = 0
+    device_eager = method is None and engine in ("jax", "sort") and array_is_jax
+    if device_eager and not keep_by_shape and datetime_dtype is None:
+        span_rows = _span_block_rows(arr, nred_shape, agg)
+    # a span-blocked reduction reads the array as it is laid out: flattening
+    # a multi-dim span relayouts the whole array
+    arr_flat = arr if span_rows else arr.reshape(lead_shape + (span,))
 
     if method is not None:
         # -- sharded SPMD reduction over the mesh ---------------------------
@@ -845,21 +852,26 @@ def _groupby_reduce_impl(
                 autotune.prime_reduce(
                     func_name, arr_flat.dtype, ncap, int(np.prod(arr_flat.shape))
                 )
-            result_c = _reduce_blockwise(
-                arr_flat,
-                ccodes,
-                agg,
-                size=ncap,
-                engine="jax",
-                datetime_dtype=datetime_dtype,
-                prog_family="sort",
-            )
+            if span_rows:
+                result_c = _span_reduce(arr, ccodes.reshape(nred_shape), agg, size=ncap, rows=span_rows)
+            else:
+                result_c = _reduce_blockwise(
+                    arr_flat,
+                    ccodes,
+                    agg,
+                    size=ncap,
+                    engine="jax",
+                    datetime_dtype=datetime_dtype,
+                    prog_family="sort",
+                )
             from .kernels import scatter_present_dense
 
             result = _redevice_scattered(
                 scatter_present_dense(np.asarray(result_c), present, size),
                 array_is_jax,
             )
+        elif span_rows:
+            result = _span_reduce(arr, codes_flat.reshape(nred_shape), agg, size=size, rows=span_rows)
         else:
             result = _reduce_blockwise(
                 arr_flat,
@@ -1303,6 +1315,86 @@ def _reduce_blockwise(arr_flat, codes_flat, agg: Aggregation, *, size, engine,
     with telemetry.span("finalize"):
         result = _astype_final(result, agg, datetime_dtype)
     return result
+
+
+def _span_block_rows(arr, nred_shape, agg: Aggregation) -> int:
+    """Rows of the first label dim in each slab of a span-blocked reduction
+    (:func:`_span_reduce`), or 0 where the span reduces whole: labels over
+    one dim, an array within one slab (``utils.SPAN_BLOCK_BYTES``), or an
+    aggregation with no chunk/combine split (order statistics)."""
+    if len(nred_shape) < 2 or agg.blockwise_only:
+        return 0
+    row_bytes = int(np.prod(arr.shape)) * np.dtype(arr.dtype).itemsize // nred_shape[0]
+    rows = utils.SPAN_BLOCK_BYTES // max(1, row_bytes)
+    if rows >= nred_shape[0]:
+        return 0
+    # whole (8, 128) tiles when the cut dim is the array's second-minor one
+    return rows // 8 * 8 if rows >= 8 else max(rows, 1)
+
+
+#: the span-blocked programs, one per resolved aggregation, group count and
+#: slab height (jax.jit keys each on its shapes)
+_SPAN_PROGRAMS = LRUCache(maxsize=64)
+
+
+def _span_reduce(arr, codes, agg: Aggregation, *, size: int, rows: int):
+    """``agg`` of the device array ``arr`` over its trailing ``codes.ndim``
+    dims, grouped by ``codes`` (shaped like those dims), in slabs of ``rows``
+    rows of the first of them. Each slab is flattened on its own and reduced
+    by the aggregation's chunk kernels to per-group intermediates, which
+    fold into the running ones with its combine ops, as the streaming
+    runtime folds host slabs; the finalized (..., size) result comes back.
+    No program holds the whole array flattened."""
+    from .options import trace_fingerprint
+    from .parallel.mapreduce import _agg_cache_key
+
+    nslabs = -(-codes.shape[0] // rows)
+    telemetry.count("reduce.span_blocks", nslabs)
+    key = (_agg_cache_key(agg), size, rows, trace_fingerprint())
+    program = _SPAN_PROGRAMS.get(key)
+    if program is None:
+        program = _SPAN_PROGRAMS[key] = _span_program(agg, size=size, rows=rows)
+    with telemetry.span("dispatch", engine="jax", size=size, span_blocks=nslabs):
+        result = program(utils.asarray_device(codes), arr)
+    with telemetry.span("finalize"):
+        return _astype_final(result, agg)
+
+
+def _span_program(agg: Aggregation, *, size: int, rows: int):
+    """The jitted ``flox_spanblock(codes, arr)`` of :func:`_span_reduce`."""
+    import jax
+
+    from .parallel.mapreduce import _finalize_combined
+    from .streaming import _init_state_like_merged, _merge_into, _slab_stats
+
+    agg = agg.without_appended_count()  # every slab's stats carry the counts
+    count_skipna = agg.name.startswith("nan") or agg.name == "count" or agg.min_count > 0
+
+    def flox_spanblock(codes, arr):
+        lead = arr.ndim - codes.ndim
+        row = codes.size // codes.shape[0]  # span elements per row
+
+        def stats(start, n):
+            slab = jax.lax.dynamic_slice_in_dim(arr, start, n, axis=lead)
+            slab = slab.reshape(slab.shape[:lead] + (n * row,))
+            c = jax.lax.dynamic_slice_in_dim(codes, start, n, axis=0).reshape(-1)
+            return _slab_stats(agg, slab, c, start * row, size=size, count_skipna=count_skipna, nat=False)
+
+        def fold(state, start, n):
+            return _merge_into(agg, state, *stats(start, n), nat=False)
+
+        with jax.named_scope("flox.spanblock"):
+            nfull, tail = divmod(codes.shape[0], rows)
+            state = _init_state_like_merged(agg, *stats(0, rows), nat=False)
+            state = jax.lax.fori_loop(1, nfull, lambda i, st: fold(st, i * rows, rows), state)
+            if tail:
+                state = fold(state, nfull * rows, tail)
+        with jax.named_scope("flox.finalize"):
+            inters, counts = state
+            return _finalize_combined(agg, inters, counts)
+
+    # the name is the program's in the profiler's trace
+    return jax.jit(flox_spanblock)
 
 
 def _where(cond, fill, x):

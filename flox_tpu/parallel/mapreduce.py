@@ -455,16 +455,7 @@ def sharded_groupby_reduce(
                 "layout — the reference forces blockwise for these too, core.py:685-709)."
             )
 
-    if agg.appended_count:
-        # the mesh programs compute counts themselves; the appended nanlen
-        # would otherwise leak into agg.finalize as a stray positional arg
-        import copy as _copy
-
-        agg = _copy.deepcopy(agg)
-        agg.chunk = agg.chunk[:-1]
-        agg.combine = agg.combine[:-1]
-        agg.fill_value["intermediate"] = agg.fill_value["intermediate"][:-1]
-        agg.appended_count = False
+    agg = agg.without_appended_count()  # the mesh programs compute counts themselves
 
     if nat:
         from ..aggregations import shift_nat_identity_fills

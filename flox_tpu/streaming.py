@@ -301,16 +301,9 @@ def _streaming_groupby_reduce_impl(
             probe.dtype if datetime_dtype is None else np.dtype("int64"),
             fill_value, 0 if min_count is None else min_count, finalize_kwargs,
         )
-        if agg.appended_count:
-            # the streaming runtime computes counts itself (count_skipna
-            # channel + _apply_final_fill threshold); the appended nanlen
-            # would otherwise leak into agg.finalize as a stray positional
-            # arg — var's ddof became a count array, poisoning every group
-            # (the same strip sharded_groupby_reduce applies)
-            agg.chunk = agg.chunk[:-1]
-            agg.combine = agg.combine[:-1]
-            agg.fill_value["intermediate"] = agg.fill_value["intermediate"][:-1]
-            agg.appended_count = False
+        # the streaming runtime computes counts itself (count_skipna
+        # channel + _apply_final_fill threshold)
+        agg = agg.without_appended_count()
     if datetime_dtype is not None:
         # same dtype round-trips as core.groupby_reduce (core.py:495-541),
         # applied PER SLAB so the conversion streams with the data

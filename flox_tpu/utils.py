@@ -182,6 +182,14 @@ def is_nan_fill(v) -> bool:
 #: the 6.9 GB headline array needed 19.2 GB of a v5e's 15.75 GB.
 ROW_BLOCK_BYTES = 2**30
 
+#: a reduction over several trailing label dims of a device array larger
+#: than this runs in slabs of the first label dim this size (core's
+#: span-blocked path). Each slab is copied once into the kernels' flat
+#: (..., N) layout and its masks and counts are slab-sized, so a slab a
+#: quarter of ROW_BLOCK_BYTES keeps the call's temporaries near 1 GiB;
+#: flattening the whole span instead relayouts the whole array.
+SPAN_BLOCK_BYTES = 2**28
+
 
 def map_row_blocks(fn, codes, array):
     """``fn(codes, array)`` for ``array`` (..., N) reduced over its trailing

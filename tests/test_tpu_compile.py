@@ -124,3 +124,34 @@ def test_kernel_carries_its_name(one_chip, case):
     codes = jax.ShapeDtypeStruct((1024,), jnp.int32, sharding=one_chip)
     text = jax.jit(lambda x, c: fn(x, c, groups)).lower(data, codes).as_text()
     assert f'kernel_name = "{KERNEL_NAMES[case]}"' in text
+
+
+def test_span_blocked_program_fits_beside_the_array(one_chip, monkeypatch):
+    """The NWM county program at a month's share over four chips, (180,
+    1800, 4500) float32 reduced by (1800, 4500) codes into 842 groups (the
+    benchmark's cell runs 120 steps), compiled as the chip builds it (the
+    ``auto`` policies' TPU branch): its temporaries stay under a quarter of
+    the input, where flattening the span first writes a relayouted copy of
+    the whole array."""
+    import numpy as np
+    from jax.experimental.layout import Format, Layout
+
+    from flox_tpu import core, kernels
+    from flox_tpu.aggregations import _initialize_aggregation
+
+    for name in ("_on_tpu", "_pallas_runtime_ok", "_pallas_radixbin_runtime_ok"):
+        monkeypatch.setattr(kernels, name, lambda: True)
+    # row-major arguments, as the chip holds them; left to itself the
+    # described compile picks a column-major parameter and adds a copy
+    arr = jax.ShapeDtypeStruct((180, 1800, 4500), jnp.float32, sharding=Format(Layout((0, 1, 2)), one_chip))
+    codes = jax.ShapeDtypeStruct((1800, 4500), jnp.int32, sharding=Format(Layout((0, 1)), one_chip))
+    agg = _initialize_aggregation("nanmean", None, np.dtype("float32"), None, 0, None)
+    rows = core._span_block_rows(arr, codes.shape, agg)
+    assert 0 < rows < 1800
+    t0 = time.perf_counter()
+    compiled = core._span_program(agg, size=842, rows=rows).lower(codes, arr).compile()
+    took = time.perf_counter() - t0
+    input_bytes = 180 * 1800 * 4500 * 4
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.25 * input_bytes
+    assert "flox_radixbin" in compiled.as_text()  # 842 groups: past the dense kernel
+    assert took < 2 * COMPILE_S_MAX, f"compile took {took:.1f} s"
